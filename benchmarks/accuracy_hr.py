@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data import synthetic
+from repro.launch.runtime import enable_compile_cache
 from repro.models import recsys as rs
 from repro.optim import adamw
 from repro.serving.recsys_engine import RecSysEngine, hit_rate
@@ -63,6 +64,7 @@ def rows(quick: bool = True):
 
 
 def main():
+    enable_compile_cache()
     for name, us, derived in rows():
         print(f"{name},{us:.6f},{derived}")
 

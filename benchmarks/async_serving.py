@@ -35,10 +35,14 @@ dominates exactly as it does at production scale.
 are the flags every serving benchmark shares, so tools/bench_compare.py
 can diff any pair of artifacts without per-benchmark special cases.
 
-Variance control (this host is a noisy 2-core container): unless the
+Variance control on the CPU platform (``JAX_PLATFORMS=cpu``): unless the
 caller already set it, ``--xla_cpu_multi_thread_eigen=false`` is appended
-to XLA_FLAGS before jax loads (Eigen's intra-op thread pool thrashing the
-2 cores was the dominant run-to-run jitter), and every server is measured
+to XLA_FLAGS before jax loads (Eigen's intra-op thread pool thrashing a
+small host's cores was the dominant run-to-run jitter), together with the
+``--devices`` virtual CPU devices; neither flag is set on any other
+platform, where the routed mesh takes the first ``--devices`` real
+devices. ``--devices 4`` rehearses the four-chip query mesh on the CPU.
+Every server is measured
 ``--repeats`` times with the best run reported — the first measured pass
 doubles as a thermal/allocator warmup on top of the compile-off-the-clock
 wave. Emits BENCH_async_serving.json (see benchmarks/bench_io.py).
@@ -46,20 +50,7 @@ wave. Emits BENCH_async_serving.json (see benchmarks/bench_io.py).
 from __future__ import annotations
 
 import argparse
-import os
 import time
-
-
-def _default_xla_cpu_flags() -> None:
-    """Append the Eigen single-thread flag unless the caller chose one.
-
-    Must run before the first jax import; both this benchmark's main() and
-    benchmarks/catalog_churn.py call it first thing.
-    """
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_cpu_multi_thread_eigen" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_cpu_multi_thread_eigen=false").strip()
 
 
 def _setup(n_items: int, scan_block: int | None, history_len: int = 12,
@@ -114,6 +105,7 @@ def rows(batch: int, n_queries: int, n_items: int, depth: int,
     import numpy as np
 
     from repro.data.synthetic import serving_queries
+    from repro.launch.mesh import make_mesh
     from repro.serving import make_server
 
     engine, data = _setup(n_items, scan_block)
@@ -128,7 +120,8 @@ def rows(batch: int, n_queries: int, n_items: int, depth: int,
                                   buckets=(batch,), depth=depth)),
     ]
     if n_devices > 1 and jax.device_count() >= n_devices:
-        mesh = jax.make_mesh((n_devices,), ("qp",))
+        mesh = make_mesh((n_devices,), ("qp",),
+                         devices=jax.devices()[:n_devices])
         routed = engine.shard(mesh, query_axis="qp")
         servers.append((
             f"pipelined_routed_qp{n_devices}",
@@ -150,7 +143,8 @@ def rows(batch: int, n_queries: int, n_items: int, depth: int,
         out.append((
             f"serving/async/{name}_batch{batch}_n{n_items}", 1e6 / q,
             f"qps={q:.0f};p50_ms={p50:.2f};p99_ms={p99:.2f};"
-            f"bitmatch_sync={bitmatch};host=CPU(container)",
+            f"bitmatch_sync={bitmatch};"
+            f"platform={jax.devices()[0].platform}",
         ))
         assert bitmatch, f"{name} diverged from the synchronous path"
     best = max(q for name, q in qps.items() if name != "sync")
@@ -179,8 +173,9 @@ def main():
                          "bench runtime); 0 forces the dense plan")
     ap.add_argument("--depth", type=int, default=2)
     ap.add_argument("--devices", type=int, default=2,
-                    help="fake CPU devices for the routed query mesh "
-                         "(set before jax import; 1 disables routing)")
+                    help="devices of the routed query mesh (virtual CPU "
+                         "devices under JAX_PLATFORMS=cpu; 1 disables "
+                         "routing)")
     ap.add_argument("--wave", type=int, default=1024,
                     help="queries submitted per serve_many call")
     ap.add_argument("--repeats", type=int, default=2,
@@ -192,11 +187,17 @@ def main():
     sizes = (tuple(int(s) for s in args.sizes.split(","))
              if args.sizes else (args.items,))
 
-    _default_xla_cpu_flags()  # must precede the first jax import
-    if args.devices > 1:  # must precede the first jax import
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.devices}")
+    from repro.launch.runtime import (
+        SINGLE_THREAD_EIGEN,
+        add_cpu_xla_flags,
+        enable_compile_cache,
+    )
+
+    # before jax creates its backends; on the CPU platform only
+    add_cpu_xla_flags(SINGLE_THREAD_EIGEN, *(
+        [f"--xla_force_host_platform_device_count={args.devices}"]
+        if args.devices > 1 else []))
+    enable_compile_cache()
 
     from benchmarks.bench_io import csv_rows_to_json, write_bench_json
 

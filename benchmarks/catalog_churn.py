@@ -290,9 +290,14 @@ def main():
     sizes = (tuple(int(s) for s in args.sizes.split(","))
              if args.sizes else (args.items,))
 
-    from benchmarks.async_serving import _default_xla_cpu_flags
+    from repro.launch.runtime import (
+        SINGLE_THREAD_EIGEN,
+        add_cpu_xla_flags,
+        enable_compile_cache,
+    )
 
-    _default_xla_cpu_flags()  # must precede the first jax import
+    add_cpu_xla_flags(SINGLE_THREAD_EIGEN)  # before jax creates backends
+    enable_compile_cache()
 
     from benchmarks.bench_io import csv_rows_to_json, write_bench_json
 

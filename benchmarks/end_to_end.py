@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import cost_model as cm
+from repro.launch.runtime import enable_compile_cache
 
 
 def rows(measure_software: bool = True):
@@ -76,6 +77,7 @@ def rows(measure_software: bool = True):
 
 
 def main():
+    enable_compile_cache()
     for name, us, derived in rows():
         print(f"{name},{us:.6f},{derived}")
 

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from repro.core.quantization import quantize_rowwise
 from repro.kernels import ops
+from repro.launch.runtime import enable_compile_cache
 
 
 def _time(fn, *args, n=5):
@@ -58,6 +59,7 @@ def rows():
 
 
 def main():
+    enable_compile_cache()
     for name, us, derived in rows():
         print(f"{name},{us:.3f},{derived}")
 

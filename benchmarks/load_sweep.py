@@ -229,9 +229,14 @@ def main():
                     help="CI fast-lane preset: 2 tenants, ~2s, 2 loads")
     args = ap.parse_args()
 
-    from benchmarks.async_serving import _default_xla_cpu_flags
+    from repro.launch.runtime import (
+        SINGLE_THREAD_EIGEN,
+        add_cpu_xla_flags,
+        enable_compile_cache,
+    )
 
-    _default_xla_cpu_flags()  # must precede the first jax import
+    add_cpu_xla_flags(SINGLE_THREAD_EIGEN)  # before jax creates backends
+    enable_compile_cache()
 
     if args.smoke:
         args.sizes, args.loads = SMOKE["sizes"], SMOKE["loads"]

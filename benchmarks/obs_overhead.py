@@ -37,6 +37,7 @@ import time
 import numpy as np
 
 from repro.data.synthetic import serving_queries as _queries
+from repro.launch.runtime import enable_compile_cache
 from repro.obs import dump_trace
 from repro.serving import make_server
 
@@ -148,6 +149,7 @@ def run(batch_sizes, repeats: int, smoke: bool, out_dir):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sizes", type=str, default="64",
                     help="comma-separated batch sizes (unified flag)")

@@ -21,6 +21,7 @@ import sys
 
 from repro.configs.base import SHAPES, active_param_count, param_count_dense
 from repro.configs.registry import ARCH_IDS, get_arch
+from repro.launch.runtime import enable_compile_cache
 
 PEAK_FLOPS = 197e12  # bf16 / chip
 HBM_BW = 819e9  # bytes/s / chip
@@ -139,6 +140,7 @@ def format_markdown(rows: list[dict]) -> str:
 
 
 def main():
+    enable_compile_cache()
     mesh = sys.argv[1] if len(sys.argv) > 1 else "single"
     rows = full_table(mesh)
     print(format_markdown(rows))

@@ -8,8 +8,11 @@ derived) is appended when experiments/dryrun/ artifacts exist.
 import argparse
 import sys
 
+from repro.launch.runtime import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="full-size accuracy run (slower)")

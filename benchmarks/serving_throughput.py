@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.data import synthetic
 from repro.data.synthetic import serving_queries as _queries
+from repro.launch.runtime import enable_compile_cache
 from repro.models import recsys as rs
 from repro.serving import RecSysEngine, make_server
 
@@ -103,6 +104,7 @@ def rows(batch_sizes=BATCH_SIZES, repeats: int = 1):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sizes", type=str,
                     default=",".join(str(b) for b in BATCH_SIZES),

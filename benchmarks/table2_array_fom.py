@@ -5,6 +5,7 @@ measured; we print them alongside the derived per-op quantities the model
 composes from them.
 """
 from repro.core.cost_model import ARRAY_FOM, CAL, e_shot
+from repro.launch.runtime import enable_compile_cache
 
 
 def rows():
@@ -17,6 +18,7 @@ def rows():
 
 
 def main():
+    enable_compile_cache()
     for name, us, derived in rows():
         print(f"{name},{us:.6f},{derived}")
 

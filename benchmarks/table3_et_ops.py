@@ -1,6 +1,7 @@
 """Paper Table III: ET-operation latency/energy, iMARS (cost model) vs the
 paper's measured GPU baselines, plus the NNS comparison of Sec. IV-C2."""
 from repro.core import cost_model as cm
+from repro.launch.runtime import enable_compile_cache
 
 
 def rows():
@@ -33,6 +34,7 @@ def rows():
 
 
 def main():
+    enable_compile_cache()
     for name, us, derived in rows():
         print(f"{name},{us:.6f},{derived}")
 

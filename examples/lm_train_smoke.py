@@ -17,6 +17,7 @@ from repro.configs.base import ModelConfig, ParallelConfig, ShapeConfig
 from repro.data.lm_data import PrefetchIterator, synthetic_token_stream
 from repro.distributed import training as tr
 from repro.distributed.fault_tolerance import FaultPolicy, TrainLoop
+from repro.launch.runtime import enable_compile_cache
 
 
 def small_lm() -> ModelConfig:
@@ -28,6 +29,7 @@ def small_lm() -> ModelConfig:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

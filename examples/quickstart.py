@@ -15,9 +15,11 @@ from repro.core.embedding import embedding_bag, init_table, table_to_dense
 from repro.core.lsh import lsh_signature, make_lsh_projections
 from repro.core.nns import fixed_radius_nns
 from repro.core.topk import threshold_topk
+from repro.launch.runtime import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     key = jax.random.key(0)
     print("== iMARS quickstart ==")
 

@@ -37,11 +37,13 @@ import numpy as np
 
 from repro.core import cost_model as cm
 from repro.data import synthetic
+from repro.launch.runtime import enable_compile_cache
 from repro.serving import RecSysEngine, make_server
 from examples.train_recsys import train
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--users", type=int, default=1000)
     ap.add_argument("--items", type=int, default=600)

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data import synthetic
+from repro.launch.runtime import enable_compile_cache
 from repro.models import recsys as rs
 from repro.serving.recsys_engine import RecSysEngine, hit_rate
 
@@ -43,6 +44,7 @@ def train(data, steps: int, seed: int = 0):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--users", type=int, default=2000)
     ap.add_argument("--items", type=int, default=1000)

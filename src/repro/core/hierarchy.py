@@ -19,7 +19,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.quantization import QuantizedTensor
 from repro.kernels import ops
-from repro.utils import shard_map
 
 
 def tree_sum(parts: jax.Array, fan_in: int = 4) -> jax.Array:
@@ -87,12 +86,12 @@ def sharded_embedding_bag(
         def fn(tv, ts, ig):
             return local(tv, ts, ig, None)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             fn, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False
         )
         return mapped(table.values, table.scales, ids)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False
     )
     return mapped(table.values, table.scales, ids, weights)

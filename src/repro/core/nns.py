@@ -49,7 +49,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
 from repro.kernels.streaming_nns import BIG_DIST
-from repro.utils import cdiv, pytree_dataclass, shard_map
+from repro.utils import cdiv, pytree_dataclass
 
 # invalid-slot distance sentinel (single definition in
 # kernels/streaming_nns.py), exported for tests
@@ -602,7 +602,7 @@ def sharded_fixed_radius_nns(
     specs_out = NNSResult(
         indices=q_spec, distances=q_spec, counts=q_spec,
         blocks_touched=q_spec if use_prune else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_scan, mesh=mesh, in_specs=specs_in, out_specs=specs_out,
         check_vma=False,
     )
@@ -661,7 +661,7 @@ def query_parallel_nns(
         specs_in = (*specs_in, P(), P(), P(), P(), P())
         args = (*args, summary.or_sigs, summary.and_sigs, summary.min_pc,
                 summary.max_pc, summary.n_alive)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_scan, mesh=mesh, in_specs=specs_in,
         out_specs=NNSResult(indices=q_spec, distances=q_spec, counts=q_spec,
                             blocks_touched=q_spec if use_prune else None),
@@ -767,7 +767,7 @@ def query_parallel_delta_scan(
         return delta_scan(q_local, dsigs, dids, radius, max_candidates)
 
     q_spec = P(query_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_scan, mesh=mesh, in_specs=(q_spec, P(), P()),
         out_specs=NNSResult(indices=q_spec, distances=q_spec,
                             counts=q_spec, blocks_touched=None),

@@ -2,15 +2,16 @@
 
 TPU adaptation of the iMARS TCAM threshold search (Sec. III-A/B): the analog
 O(1) matchline compare becomes a VPU-rate XOR + popcount sweep over uint32
-lanes. Signatures are packed 256 bits -> 8 x uint32, so one (block_n, 8)
+lanes. Signatures are packed 256 bits -> 8 x uint32, so one (8, block_n)
 VMEM tile covers block_n items; the kernel emits raw distances and the
 threshold (fixed-radius) selection stays in plain XLA (it is a trivial
 compare + top-k over the int32 distance matrix).
 
-Block geometry: db tile (block_n, words) and query tile (block_q, words) live
-in VMEM; output tile is (block_q, block_n) int32. With block_n = 1024 and
-words = 8 the working set is ~32 KiB db + 4 MiB out per step — well inside
-the ~16 MiB v5e VMEM, and the lane dimension (block_n) is a multiple of 128.
+Block geometry: the DB is read word-major, as (words, n) — the order XLA
+already keeps a (n, words) signature array in on TPU, so the transpose is
+free there — in lane-dense (words, block_n) tiles; the query tile is
+(block_q, words) and the output tile (block_q, block_n) int32. With
+block_n = 1024 the working set is a few tens of KiB per step.
 """
 from __future__ import annotations
 
@@ -20,16 +21,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.streaming_nns import block_distances
 from repro.utils import cdiv
 
 
 def _hamming_kernel(q_ref, db_ref, out_ref):
-    q = q_ref[...]  # (block_q, words) uint32
-    db = db_ref[...]  # (block_n, words) uint32
-    x = jnp.bitwise_xor(q[:, None, :], db[None, :, :])  # (bq, bn, w)
-    out_ref[...] = jnp.sum(
-        jax.lax.population_count(x).astype(jnp.int32), axis=-1
-    )
+    out_ref[...] = block_distances(q_ref[...], db_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_n", "interpret"))
@@ -59,10 +56,10 @@ def hamming_distances_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_q, words), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, words), lambda i, j: (j, 0)),
+            pl.BlockSpec((words, block_n), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_q, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp, np_), jnp.int32),
         interpret=interpret,
-    )(queries_p, db_p)
+    )(queries_p, db_p.T)
     return out[:q, :n]

@@ -133,12 +133,13 @@ def _streaming_nns_ref(queries, db, *, radius, max_candidates, scan_block,
         prune_blocks=prune_blocks, prune_block_rows=prune_block_rows)
 
 
-# the kernel's rank-select merge materializes an (block_q, m, m) compare with
-# m = block_n + padded-K; 512 rows keeps that ~13 MiB — inside VMEM. The
-# `scan_block` knob sizes the *ref* lax.scan chunk; the pallas tile is
-# derived independently (128-lane aligned, capped) so any host-side chunk —
-# huge or oddly-sized — maps to a viable on-chip merge tile. Results are
-# block-size invariant, so the remap never changes output.
+# the kernel's merge holds (block_q, k_pad + block_n) int32 keys per round,
+# so its VMEM footprint is small; 512 rows bounds the per-round lane
+# reductions of a merging block. The `scan_block` knob sizes the *ref*
+# lax.scan chunk; the pallas tile is derived independently (128-lane
+# aligned, capped) so any host-side chunk — huge or oddly-sized — maps to
+# a viable on-chip tile. Results are block-size invariant, so the remap
+# never changes output.
 _STREAM_PALLAS_MAX_BLOCK_N = 512
 
 

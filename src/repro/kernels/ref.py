@@ -33,13 +33,19 @@ def embedding_pool_ref(
     ids: jax.Array,  # (B, L) int32, -1 = padding
     weights: jax.Array | None = None,  # (B, L) f32
 ) -> jax.Array:
-    """Fused int8 dequant-gather-pool -> (B, d) f32."""
+    """Fused int8 dequant-gather-pool -> (B, d) f32.
+
+    The slot contraction runs at full f32 precision (a TPU matmul would
+    otherwise round its inputs to bf16), so a single-slot bag is exactly
+    row * scale * weight — the kernel's value bit for bit.
+    """
     valid = (ids >= 0).astype(jnp.float32)
     safe_ids = jnp.maximum(ids, 0)
     rows = table_values[safe_ids].astype(jnp.float32)  # (B, L, d)
     scales = table_scales[safe_ids]  # (B, L, 1)
     w = valid if weights is None else weights * valid
-    return jnp.einsum("bld,bl->bd", rows * scales, w)
+    return jnp.einsum("bld,bl->bd", rows * scales, w,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 # ---------------------------------------------------------------------------

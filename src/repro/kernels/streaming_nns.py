@@ -24,8 +24,8 @@ ties by lower index — so the streaming result is bit-identical to the dense
 only index `2**shift` rows (4.19M at 256-bit signatures). Instead of paying
 for two-word (dist, row_hi/row_lo) keys everywhere, the scan is split into
 *superblocks* of at most `2**shift` rows: the row bits of every key hold the
-offset *within the current superblock* (so the in-kernel rank-select merge
-stays pure int32), each superblock accumulates its own resident candidate
+offset *within the current superblock* (so the in-kernel merge stays
+pure int32), each superblock accumulates its own resident candidate
 buffer, and full row ids are reconstructed on the host as
 ``superblock * superblock_rows + local_row``. The per-superblock top-K
 buffers are then merged host-side by one *stable* sort on distance
@@ -34,20 +34,22 @@ superblock row ranges are disjoint and ascending, so stability alone
 reproduces the exact global (distance, index) order. DB capacity becomes
 int32 row ids (2**31 rows) rather than `2**shift`.
 
-The per-block merge keeps the buffer sorted: concatenate the resident buffer
-with the block's candidate keys, compute each element's rank with one
-all-pairs compare (rank = #strictly-smaller keys; valid keys are unique so
-ranks are collision-free), and scatter rank < K survivors back via a
-min-reduction over a one-hot slot mask — all elementwise/reduce ops that
-Mosaic lowers without needing an in-kernel sort. Blocks with no matches (the
-common case at selective radii) skip the merge entirely under `pl.when`.
+The per-block merge keeps the buffer sorted: `max_candidates` rounds each
+take every query row's current minimum over the resident buffer and the
+block's candidate keys into the next buffer slot and retire that key
+(valid keys are unique, so exactly one leaves per round). Every value is a
+2-D (block_q, lanes) array — lane reductions and selects that Mosaic
+lowers on v5e, with no in-kernel sort, reshape or 3-D broadcast. Blocks
+with no matches (the common case at selective radii) skip the merge
+entirely under `pl.when`.
 
 Grid: (q_blocks, n_blocks) with the DB dimension innermost and *sequential*
 — the (1, block_q, K) candidate tile is revisited across its superblock's
 blocks and stays resident in VMEM, the same accumulator pattern as the
 embedding-pool kernel; it re-initializes when the scan crosses into the next
-superblock. `n_valid` rides along as a dynamic (1, 1) scalar operand so the
-sharded path can mask per-shard padding rows with a traced value.
+superblock. `n_valid` rides along as a dynamic scalar-prefetch operand
+(SMEM) so the sharded path can mask per-shard padding rows with a traced
+value.
 
 **Row eligibility (`db_mask`).** The live-catalog layer (serving/catalog.py)
 tombstones base rows that were deleted or overwritten by a delta row; those
@@ -66,6 +68,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.utils import cdiv, round_up
 
@@ -163,9 +166,46 @@ def merge_chunk_buffers(chunks, max_candidates: int):
     return merge_candidate_buffers(idx, dist, max_candidates)
 
 
-def _streaming_nns_kernel(limit_ref, q_ref, db_ref, keys_ref, counts_ref,
-                          *, radius, shift, big, blocks_per_sb,
-                          mask_ref=None, scan_ref=None):
+def block_distances(q, db_t):
+    """(bq, words) x (words, bn) packed signatures -> (bq, bn) int32.
+
+    The XOR-popcount of both Hamming kernels, one signature word at a
+    time over the word-major DB tile: every intermediate is 2-D with the
+    DB rows on the lanes, which is what Mosaic lays out without reshapes.
+    """
+    d = jnp.zeros((q.shape[0], db_t.shape[1]), jnp.int32)
+    for w in range(db_t.shape[0]):
+        x = jnp.bitwise_xor(q[:, w:w + 1], db_t[w:w + 1, :])
+        d = d + jax.lax.population_count(x).astype(jnp.int32)
+    return d
+
+
+def _merge_smallest(buf, new_keys, *, n_keep, big):
+    """Sorted `n_keep` smallest keys of `buf` (bq, k_pad) + `new_keys`.
+
+    Valid keys are unique (distinct rows), so each round takes every
+    row's current minimum into the next slot and retires exactly that
+    key; slots past the valid keys (and past `n_keep`) stay `big`.
+    """
+    slot = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 1)
+
+    def take_min(k, carry):
+        buf, new, out = carry
+        mn = jnp.minimum(jnp.min(buf, axis=1, keepdims=True),
+                         jnp.min(new, axis=1, keepdims=True))
+        out = jnp.where(slot == k, mn, out)
+        return (jnp.where(buf == mn, big, buf),
+                jnp.where(new == mn, big, new), out)
+
+    out = jnp.full(buf.shape, big, jnp.int32)
+    return jax.lax.fori_loop(0, n_keep, take_min, (buf, new_keys, out))[2]
+
+
+def _streaming_nns_kernel(limit_ref, q_ref, db_ref, *refs, radius, shift,
+                          big, blocks_per_sb, n_keep, has_mask, has_prune):
+    mask_ref = refs[0] if has_mask else None
+    scan_ref = refs[int(has_mask)] if has_prune else None
+    keys_ref, counts_ref = refs[-2:]
     j = pl.program_id(1)
 
     # buffer inits stay OUTSIDE the prune predicate: a superblock whose
@@ -180,65 +220,32 @@ def _streaming_nns_kernel(limit_ref, q_ref, db_ref, keys_ref, counts_ref,
         counts_ref[...] = jnp.zeros(counts_ref.shape, jnp.int32)
 
     def _scan_block():
-        q = q_ref[...]  # (block_q, words) uint32
-        db = db_ref[...]  # (block_n, words) uint32
-        block_n = db.shape[0]
-        x = jnp.bitwise_xor(q[:, None, :], db[None, :, :])
-        d = jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=-1)
+        d = block_distances(q_ref[...], db_ref[...])  # (block_q, block_n)
+        block_n = d.shape[1]
         iota = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
         gidx = j * block_n + iota  # global row id (int32-safe to 2**31 rows)
-        within = jnp.logical_and(d <= radius, gidx < limit_ref[0, 0])
+        within = jnp.logical_and(d <= radius, gidx < limit_ref[0])
         if mask_ref is not None:  # tombstoned rows never match (matchline AND)
-            within = jnp.logical_and(within, (mask_ref[...] != 0)[0][None, :])
-        counts_ref[...] += jnp.sum(within.astype(jnp.int32), axis=1,
-                                   keepdims=True)
+            within = jnp.logical_and(within, mask_ref[...] != 0)
+        hits = within.astype(jnp.int32)
+        counts_ref[...] += jnp.sum(hits, axis=1, keepdims=True)
 
-        @pl.when(jnp.any(within))
+        @pl.when(jnp.max(hits) > 0)
         def _merge():
             # row bits carry the superblock-LOCAL offset: int32 keys
             lidx = (j % blocks_per_sb) * block_n + iota
             new_keys = jnp.where(within, d * (1 << shift) + lidx, big)
-            merged = jnp.concatenate([keys_ref[0], new_keys], axis=1)
-            rank = jnp.sum(
-                (merged[:, None, :] < merged[:, :, None]).astype(jnp.int32),
-                axis=-1,
-            )  # (bq, m): unique for valid keys, >= K only past-K sentinels
-            n_slots = keys_ref.shape[2]
-            slot = jax.lax.broadcasted_iota(
-                jnp.int32, (*merged.shape, n_slots), 2)
-            take = jnp.logical_and(rank[..., None] == slot,
-                                   (merged < big)[..., None])
-            keys_ref[0] = jnp.min(
-                jnp.where(take, merged[..., None], big), axis=1)
+            keys_ref[0] = _merge_smallest(keys_ref[0], new_keys,
+                                          n_keep=n_keep, big=big)
 
     if scan_ref is None:
         _scan_block()
     else:
         # block-summary pruning: this (query-tile, db-block) cell was
-        # proven empty of matches by the sound bound — skip all of it
-        pl.when(scan_ref[0, 0] != 0)(_scan_block)
-
-
-def _masked_streaming_nns_kernel(limit_ref, q_ref, db_ref, mask_ref,
-                                 keys_ref, counts_ref, **kw):
-    """Mask-carrying variant: same body, one extra (1, block_n) operand."""
-    _streaming_nns_kernel(limit_ref, q_ref, db_ref, keys_ref, counts_ref,
-                          mask_ref=mask_ref, **kw)
-
-
-def _pruned_streaming_nns_kernel(limit_ref, q_ref, db_ref, scan_ref,
-                                 keys_ref, counts_ref, **kw):
-    """Prune-carrying variant: same body, one extra (1, 1) cell operand."""
-    _streaming_nns_kernel(limit_ref, q_ref, db_ref, keys_ref, counts_ref,
-                          scan_ref=scan_ref, **kw)
-
-
-def _masked_pruned_streaming_nns_kernel(limit_ref, q_ref, db_ref, mask_ref,
-                                        scan_ref, keys_ref, counts_ref,
-                                        **kw):
-    """Mask + prune variant: both extra operands, same body."""
-    _streaming_nns_kernel(limit_ref, q_ref, db_ref, keys_ref, counts_ref,
-                          mask_ref=mask_ref, scan_ref=scan_ref, **kw)
+        # proven empty of matches by the sound bound — skip all of it.
+        # Cells are bit-packed 32 blocks per SMEM word.
+        word = scan_ref[0, 0, j // 32]
+        pl.when(jnp.right_shift(word, j % 32) & 1 != 0)(_scan_block)
 
 
 @functools.partial(
@@ -273,13 +280,19 @@ def streaming_nns_pallas(
 
     **Block pruning.** `prune_blocks` ((q, nb) bool from the core
     `BlockSummary` bounds, `prune_block_rows` rows per summary block,
-    which must be a multiple of `block_n`) adds a (1, 1) int32 cell
-    operand gridded per (query-tile, db-block): when every query of the
-    tile prunes the block, the whole kernel body is predicated off with
-    `pl.when` — no distance, no merge, no count. The candidate/count
-    buffer inits stay outside the predicate so fully-pruned superblocks
-    still emit well-formed (empty) buffers. Sound bound => bit-identical
-    outputs.
+    which must be a multiple of `block_n`) adds a per-(query-tile,
+    db-block) scan bit, packed 32 blocks per int32 word into one SMEM row
+    per query tile: when every query of the tile prunes the block, the
+    whole kernel body is predicated off with `pl.when` — no distance, no
+    merge, no count. The candidate/count buffer inits stay outside the
+    predicate so fully-pruned superblocks still emit well-formed (empty)
+    buffers. Sound bound => bit-identical outputs.
+
+    **Chip layout.** The kernel reads the DB word-major, as (words, n):
+    XLA keeps a (n, words) signature array in exactly that physical order
+    on TPU, so the transpose below is free there, and every DB tile is a
+    lane-dense (words, block_n) block. `n_valid` rides in SMEM as a
+    scalar-prefetch operand.
     """
     q, words = queries.shape
     n, words2 = db.shape
@@ -298,20 +311,19 @@ def streaming_nns_pallas(
     queries_p = jnp.pad(queries, ((0, qp - q), (0, 0))) if qp > q else queries
     db_p = jnp.pad(db, ((0, np_ - n), (0, 0))) if np_ > n else db
     limit = jnp.reshape(
-        jnp.minimum(jnp.asarray(n_valid, jnp.int32), n), (1, 1))
+        jnp.minimum(jnp.asarray(n_valid, jnp.int32), n), (1,))
 
-    operands = [limit, queries_p, db_p]
+    operands = [limit, queries_p, db_p.T]
     in_specs = [
-        pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        pl.BlockSpec((block_q, words), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_n, words), lambda i, j: (j, 0)),
+        pl.BlockSpec((block_q, words), lambda i, j, lim: (i, 0)),
+        pl.BlockSpec((words, block_n), lambda i, j, lim: (0, j)),
     ]
     if db_mask is not None:
         mask = jnp.reshape(db_mask.astype(jnp.int32), (1, n))
         if np_ > n:  # pad rows ineligible (n_valid already excludes them)
             mask = jnp.pad(mask, ((0, 0), (0, np_ - n)))
         operands.append(mask)
-        in_specs.append(pl.BlockSpec((1, block_n), lambda i, j: (0, j)))
+        in_specs.append(pl.BlockSpec((1, block_n), lambda i, j, lim: (0, j)))
     if prune_blocks is not None:
         if prune_block_rows is None or prune_block_rows % block_n:
             raise ValueError(
@@ -324,33 +336,36 @@ def streaming_nns_pallas(
             needed = jnp.pad(needed, ((0, qp - q), (0, 0)))
         nb = needed.shape[1]
         needed = jnp.any(needed.reshape(qp // block_q, block_q, nb), axis=1)
-        cells = jnp.repeat(needed, prune_block_rows // block_n, axis=1)
-        if cells.shape[1] < n_blocks:  # rows beyond coverage always scan
-            cells = jnp.pad(
-                cells, ((0, 0), (0, n_blocks - cells.shape[1])),
-                constant_values=True)
-        else:
-            cells = cells[:, :n_blocks]
-        operands.append(cells.astype(jnp.int32))
-        in_specs.append(pl.BlockSpec((1, 1), lambda i, j: (i, j)))
-    body = {
-        (False, False): _streaming_nns_kernel,
-        (True, False): _masked_streaming_nns_kernel,
-        (False, True): _pruned_streaming_nns_kernel,
-        (True, True): _masked_pruned_streaming_nns_kernel,
-    }[(db_mask is not None, prune_blocks is not None)]
+        cells = jnp.repeat(needed, prune_block_rows // block_n,
+                           axis=1)[:, :n_blocks]
+        n_words = cdiv(n_blocks, 32)
+        # rows beyond the summary's coverage always scan
+        cells = jnp.pad(cells, ((0, 0), (0, 32 * n_words - cells.shape[1])),
+                        constant_values=True)
+        bits = cells.reshape(-1, n_words, 32).astype(jnp.uint32)
+        packed = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                         dtype=jnp.uint32)
+        operands.append(jax.lax.bitcast_convert_type(packed, jnp.int32)
+                        .reshape(-1, 1, n_words))
+        in_specs.append(pl.BlockSpec((1, 1, n_words),
+                                     lambda i, j, lim: (i, 0, 0),
+                                     memory_space=pltpu.SMEM))
 
     kernel = functools.partial(
-        body, radius=radius, shift=shift, big=big,
-        blocks_per_sb=blocks_per_sb)
+        _streaming_nns_kernel, radius=radius, shift=shift, big=big,
+        blocks_per_sb=blocks_per_sb, n_keep=max_candidates,
+        has_mask=db_mask is not None, has_prune=prune_blocks is not None)
     keys, counts = pl.pallas_call(
         kernel,
-        grid=(qp // block_q, n_blocks),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, block_q, k_pad),
-                         lambda i, j: (j // blocks_per_sb, i, 0)),
-            pl.BlockSpec((block_q, 1), lambda i, j: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(qp // block_q, n_blocks),
+            in_specs=in_specs,
+            out_specs=(
+                pl.BlockSpec((1, block_q, k_pad),
+                             lambda i, j, lim: (j // blocks_per_sb, i, 0)),
+                pl.BlockSpec((block_q, 1), lambda i, j, lim: (i, 0)),
+            ),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((n_sb, qp, k_pad), jnp.int32),

@@ -387,9 +387,29 @@ def _nns(engine: RecSysEngine, q_sigs: jax.Array) -> NNSResult:
             engine.delta.sigs, engine.delta.ids, engine.radius,
             engine.n_candidates)
     else:
-        pending = delta_scan(q_sigs, engine.delta.sigs, engine.delta.ids,
-                             engine.radius, engine.n_candidates)
+        pending = _per_device(
+            engine, partial(delta_scan, radius=engine.radius,
+                            max_candidates=engine.n_candidates),
+            q_sigs, engine.delta.sigs, engine.delta.ids)
     return merge_delta_candidates(base, pending, engine.n_candidates)
+
+
+def _per_device(engine: RecSysEngine, fn, *args):
+    """`fn(*args)` on replicated array inputs, once per device of the
+    engine's NNS mesh.
+
+    A program that touches a mesh-placed array spans the whole mesh, and
+    XLA cannot partition a Pallas (Mosaic) kernel, so on a sharded engine
+    every kernel call outside the scan's own `shard_map` must sit inside
+    one: each device then runs the kernel on its full replica. Local
+    engines call `fn` directly.
+    """
+    if engine.nns_mesh is None:
+        return fn(*args)
+    from jax.sharding import PartitionSpec as P
+
+    return jax.shard_map(fn, mesh=engine.nns_mesh, in_specs=P(),
+                         out_specs=P(), check_vma=False)(*args)
 
 
 def _filter_step(engine: RecSysEngine, batch: dict):
@@ -415,7 +435,8 @@ def _rank(engine: RecSysEngine, batch: dict, cand: jax.Array,
     # current rows, not the stale base).
     items, st = delta_cached_rows(engine.delta, engine.item_hot,
                                   engine.item_table_q, cand)
-    genre = embedding_bag(engine.genre_table_q, batch["genre"][:, None])
+    genre = _per_device(engine, embedding_bag, engine.genre_table_q,
+                        batch["genre"][:, None])
     B, N = cand.shape
     ctx = jnp.concatenate([u, genre, pooled], axis=-1)
     x = jnp.concatenate(

@@ -9,23 +9,6 @@ import jax
 import jax.numpy as jnp
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` across jax versions.
-
-    Newer jax exposes `jax.shard_map(..., check_vma=...)`; older releases
-    only have `jax.experimental.shard_map.shard_map(..., check_rep=...)`.
-    Every call site in this repo goes through here so the codebase runs on
-    both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 class FrozenMapping(Mapping):
     """Immutable, hashable mapping — a dict that can live in a static
     (metadata) field of a jit-traced pytree."""

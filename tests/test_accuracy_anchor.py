@@ -10,14 +10,14 @@ numerics (key packing, merge order, radius semantics) moves at least one
 full user (1/400 = 2.5e-3) and trips the assert, while jit scheduling noise
 cannot: the whole pipeline is integer/deterministic for fixed seeds.
 
-Tolerance policy: the goldens are pinned to the jax version nightly
-installs (jax[cpu]==0.4.37 — see .github/workflows/nightly.yml); float
-TRAINING is reduction-order sensitive, so an XLA upgrade may legally move
-the trained embeddings and therefore every downstream HR number, while the
-retrieval pipeline itself stays bit-deterministic for a fixed table. The
-+-1e-3 band is deliberately tighter than one eval user (2.5e-3): it only
-admits exact agreement, and exists so the assert message shows the
-measured value.  On a jax bump: re-measure via
+Tolerance policy: the goldens are pinned to the one jax version the repo
+installs (the `jax==` pin in pyproject.toml, used by CI, nightly and every
+other run); float TRAINING is reduction-order sensitive, so an XLA upgrade
+may legally move the trained embeddings and therefore every downstream HR
+number, while the retrieval pipeline itself stays bit-deterministic for a
+fixed table. The +-1e-3 band is deliberately tighter than one eval user
+(2.5e-3): it only admits exact agreement, and exists so the assert message
+shows the measured value.  On a jax bump: re-measure via
 ``train_and_eval(n_users=400, n_items=3000, steps=1500, radius=144,
 seed=0, scan_block=512)``, confirm the ordering asserts below still hold,
 update GOLDEN in the same commit as the pin, and note the move here.
@@ -28,15 +28,12 @@ import pytest
 
 pytestmark = pytest.mark.slow
 
-# measured on the pinned seeds (n_users=400, n_items=3000, steps=1500,
-# radius=144, seed=0, scan_block=512) — see benchmarks/accuracy_hr.py.
-# radius is re-tuned for the 10x catalog: at 3000 items the 300-item quick
-# radius (112) retrieves nothing (lsh HR 0.0) and 128 retrieves BELOW
-# chance on jax 0.4.37 (lsh 0.0025 < 0.0033); 144 restores the paper's
-# fp32 ~ int8 > lsh > chance structure (chance = 10/3000 = 0.0033), and
-# the sweep is flat there (136-168 all land lsh = 0.005), so the anchor
-# is not sitting on a radius cliff
-GOLDEN = {"fp32": 0.01, "int8": 0.01, "lsh": 0.005}
+# measured on jax 0.9.0 with the pinned seeds (n_users=400, n_items=3000,
+# steps=1500, radius=144, seed=0, scan_block=512) — see
+# benchmarks/accuracy_hr.py. Radius sweep on that build: lsh HR is 0.0 at
+# 96-112, 0.0125 at 128 and 0.02 flat from 144 to 192, so the anchor sits
+# on a plateau, not a radius cliff, at 6x chance (10/3000 = 0.0033).
+GOLDEN = {"fp32": 0.0125, "int8": 0.0125, "lsh": 0.02}
 
 
 def test_hr10_streaming_10x_catalog_matches_goldens():
@@ -51,7 +48,7 @@ def test_hr10_streaming_10x_catalog_matches_goldens():
     # well above chance
     assert abs(hrs["fp32"] - hrs["int8"]) < 0.05
     assert hrs["lsh"] <= hrs["int8"] + 0.02
-    assert hrs["lsh"] > 1.2 * 10 / 3000
+    assert hrs["lsh"] > 3 * 10 / 3000
 
 
 def test_streaming_and_dense_hr_identical():

@@ -23,6 +23,7 @@ from repro.serving import (
     serve_step,
 )
 from repro.serving.hot_cache import CacheStats
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
@@ -95,7 +96,7 @@ def test_routed_pipelined_bitmatches_synchronous(served):
     """An engine sharded over a query mesh axis auto-coalesces buckets onto
     the query shards; served results must not change."""
     engine, data = served
-    mesh = jax.make_mesh((1,), ("qp",))
+    mesh = make_mesh((1,), ("qp",))
     routed = engine.shard(mesh, query_axis="qp")
     pipe = AsyncServer(routed, max_batch=8, depth=2)
     assert pipe.coalesce == 1  # one device -> one query block per dispatch
@@ -159,7 +160,7 @@ def test_staged_steps_respect_engine_knobs(served):
     base = engine.serve(batch)
     for eng in (
         dataclasses.replace(engine, scan_block=16),
-        engine.shard(jax.make_mesh((1,), ("model",)), "model"),
+        engine.shard(make_mesh((1,), ("model",)), "model"),
     ):
         u, pooled, stats = lookup_step(eng, batch, CacheStats.zero())
         nns = scan_step(eng, u)

@@ -19,6 +19,7 @@ from repro.serving import (
     serve_step,
 )
 from repro.serving.hot_cache import CacheStats
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -224,7 +225,7 @@ def test_tail_bucket_pads_with_invalid_ids(served):
 def test_sharded_engine_matches_local(served):
     """CPU 1-device mesh: sharded filter stage == single-device, end to end."""
     engine, data = served
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     sharded = engine.shard(mesh, "model")
     batch = _batch(data, np.arange(6))
     local, dist = engine.serve(batch), sharded.serve(batch)
@@ -244,7 +245,7 @@ def test_engine_scan_block_serves_identically(served):
     base = engine.serve(batch)
     for eng in (
         dataclasses.replace(engine, scan_block=16),
-        dataclasses.replace(engine.shard(jax.make_mesh((1,), ("model",)),
+        dataclasses.replace(engine.shard(make_mesh((1,), ("model",)),
                                          "model"), scan_block=8),
     ):
         got = eng.serve(batch)
@@ -262,8 +263,8 @@ def test_query_parallel_engine_matches_local(served):
     engine, data = served
     batch = _batch(data, np.arange(6))
     base = engine.serve(batch)
-    qp_only = engine.shard(jax.make_mesh((1,), ("qp",)), query_axis="qp")
-    both = engine.shard(jax.make_mesh((1, 1), ("qp", "banks")), "banks",
+    qp_only = engine.shard(make_mesh((1,), ("qp",)), query_axis="qp")
+    both = engine.shard(make_mesh((1, 1), ("qp", "banks")), "banks",
                         query_axis="qp")
     for eng in (qp_only, both):
         got = eng.serve(batch)
@@ -274,7 +275,7 @@ def test_query_parallel_engine_matches_local(served):
         np.testing.assert_array_equal(np.asarray(base.nns.counts),
                                       np.asarray(got.nns.counts))
     with pytest.raises(ValueError, match="query_axis"):
-        engine.shard(jax.make_mesh((1,), ("qp",)))
+        engine.shard(make_mesh((1,), ("qp",)))
 
 
 def test_query_parallel_engine_masks_padded_sigs(served):
@@ -289,7 +290,7 @@ def test_query_parallel_engine_masks_padded_sigs(served):
     n_items = engine.item_table_q.shape[0]
     padded = jnp.pad(engine.item_sigs, ((0, 3), (0, 0)))  # all-zero sigs
     qp = dataclasses.replace(engine, item_sigs=padded).shard(
-        jax.make_mesh((1,), ("qp",)), query_axis="qp")
+        make_mesh((1,), ("qp",)), query_axis="qp")
     got = qp.serve(batch)
     assert (np.asarray(got.nns.indices) < n_items).all()
     np.testing.assert_array_equal(np.asarray(want.items),
@@ -304,7 +305,7 @@ def test_sharded_nns_with_padding_excludes_pad_rows(key):
     x = jax.random.normal(jax.random.key(5), (37, 16))
     sigs = lsh_signature(x, proj)
     padded = jnp.pad(sigs, ((0, 3), (0, 0)))  # 40 rows, 3 pads
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     local = fixed_radius_nns(sigs[:4], sigs, radius=28, max_candidates=12)
     shard = sharded_fixed_radius_nns(mesh, "model", sigs[:4], padded,
                                      radius=28, max_candidates=12, n_valid=37)

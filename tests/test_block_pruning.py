@@ -29,6 +29,7 @@ from repro.core.nns import (
     update_block_summary,
 )
 from repro.kernels.ref import hamming_distance_ref
+from repro.launch.mesh import make_mesh
 
 WORDS = 8
 K = 16
@@ -242,12 +243,12 @@ def test_pruned_distributed_bit_matches(path):
     queries, db = _clustered(rng)
     summary = build_block_summary(db, BR)
     if path == "sharded":
-        mesh = jax.make_mesh((1,), ("banks",))
+        mesh = make_mesh((1,), ("banks",))
         run = lambda **kw: sharded_fixed_radius_nns(  # noqa: E731
             mesh, "banks", jnp.asarray(queries), jnp.asarray(db), 12, K,
             scan_block=24, **kw)
     else:
-        mesh = jax.make_mesh((1,), ("qp",))
+        mesh = make_mesh((1,), ("qp",))
         run = lambda **kw: query_parallel_nns(  # noqa: E731
             mesh, "qp", jnp.asarray(queries), jnp.asarray(db), 12, K,
             scan_block=24, **kw)
@@ -264,7 +265,7 @@ def test_sharded_misaligned_summary_falls_back_unpruned():
     rng = np.random.default_rng(14)
     queries, db = _clustered(rng, n_clusters=3)  # n=384; summary at 256
     summary = build_block_summary(db, 256)
-    mesh = jax.make_mesh((1,), ("banks",))
+    mesh = make_mesh((1,), ("banks",))
     pruned = sharded_fixed_radius_nns(
         mesh, "banks", jnp.asarray(queries), jnp.asarray(db[:300]), 12, K,
         scan_block=24, summary=summary)

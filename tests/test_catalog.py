@@ -35,6 +35,7 @@ from repro.serving import (
     pin_rows,
 )
 from repro.serving.hot_cache import INVALID_ID, cached_lookup
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
@@ -156,7 +157,7 @@ def test_query_parallel_delta_scan_matches_replicated():
     dids = np.full(32, EMPTY_ID, np.int32)
     dids[:10] = np.sort(rng.choice(500, 10, replace=False))
     dids = jnp.asarray(dids)
-    mesh = jax.make_mesh((1,), ("qp",))
+    mesh = make_mesh((1,), ("qp",))
     want = delta_scan(qs, dsigs, dids, 120, 16)
     got = query_parallel_delta_scan(mesh, "qp", qs, dsigs, dids, 120, 16)
     for name, a, b in zip(("indices", "distances", "counts"), want, got):
@@ -183,13 +184,14 @@ def test_query_parallel_delta_scan_two_devices_subprocess():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.nns import (EMPTY_ID, delta_scan,
                                     query_parallel_delta_scan)
+        from repro.launch.mesh import make_mesh
         rng = np.random.default_rng(0)
         qs = jnp.asarray(rng.integers(0, 2**32, (5, 8), dtype=np.uint32))
         dsigs = jnp.asarray(rng.integers(0, 2**32, (64, 8), dtype=np.uint32))
         dids = np.full(64, EMPTY_ID, np.int32)
         dids[:20] = np.sort(rng.choice(900, 20, replace=False))
         dids = jnp.asarray(dids)
-        mesh = jax.make_mesh((2,), ("qp",))
+        mesh = make_mesh((2,), ("qp",))
         want = delta_scan(qs, dsigs, dids, 110, 16)
         got = query_parallel_delta_scan(mesh, "qp", qs, dsigs, dids, 110, 16)
         for a, b in zip(want, got):
@@ -369,8 +371,8 @@ def test_live_serving_on_mesh_plans(served):
     cat.delete([2])
     queries = _queries(data, np.arange(9) % 60)
     want = _serve(cat.engine, queries)
-    mesh = jax.make_mesh((1,), ("banks",))
-    qmesh = jax.make_mesh((1,), ("qp",))
+    mesh = make_mesh((1,), ("banks",))
+    qmesh = make_mesh((1,), ("qp",))
     for live in (cat.engine.shard(mesh, "banks"),
                  cat.engine.shard(qmesh, query_axis="qp"),
                  cat.engine.compact().shard(mesh, "banks")):

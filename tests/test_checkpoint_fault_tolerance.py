@@ -9,6 +9,7 @@ from repro.distributed.fault_tolerance import (
     SimulatedTransientFailure,
     TrainLoop,
 )
+from repro.launch.mesh import make_mesh
 
 
 def _state(v=0.0):
@@ -148,7 +149,7 @@ def test_elastic_restore_different_sharding(tmp_path):
 
     s = {"w": jnp.arange(16.0).reshape(4, 4)}
     save(tmp_path, 1, s)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shard = {"w": NamedSharding(mesh, P("data", None))}
     r = restore(tmp_path, 1, jax.eval_shape(lambda: s), shardings=shard)
     np.testing.assert_array_equal(np.asarray(r["w"]), np.asarray(s["w"]))

@@ -11,7 +11,7 @@ from repro.core.embedding import (
 )
 from repro.core.hierarchy import hierarchical_psum, sharded_embedding_bag, tree_sum
 from repro.kernels.ref import embedding_pool_ref
-from repro.utils import shard_map
+from repro.launch.mesh import make_mesh
 
 
 def test_lookup_matches_dense(key):
@@ -63,12 +63,12 @@ def test_tree_sum_matches_sum_any_fanin(key):
 
 
 def test_hierarchical_psum_single_device(key):
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
 
     def f(x):
         return hierarchical_psum(x, ("model",))
 
-    y = shard_map(f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
+    y = jax.shard_map(f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
                       out_specs=jax.sharding.PartitionSpec(), check_vma=False)(
         jnp.ones((4,))
     )
@@ -76,7 +76,7 @@ def test_hierarchical_psum_single_device(key):
 
 
 def test_sharded_embedding_bag_matches_local(key):
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     t = init_table(key, 64, 16)
     ids = jnp.array([[1, 5, 63, -1], [0, -1, -1, -1]])
     want = embedding_pool_ref(t.values, t.scales, ids)

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import pytest
 
 from repro.launch.hlo_analysis import analyze_hlo, parse_hlo
-from repro.utils import shard_map
 
 
 def _compile(fn, *args):
@@ -70,10 +69,10 @@ def test_collective_bytes_with_groups():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.hlo_analysis import analyze_hlo
-        from repro.utils import shard_map
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         def f(x):
-            return shard_map(lambda a: jax.lax.psum(a, "model"),
+            return jax.shard_map(lambda a: jax.lax.psum(a, "model"),
                                  mesh=mesh, in_specs=P("model", None),
                                  out_specs=P(), check_vma=False)(x)
         xs = jax.ShapeDtypeStruct((8, 1024), jnp.float32)
@@ -84,14 +83,18 @@ def test_collective_bytes_with_groups():
         assert "all-reduce" in st.per_collective, st.per_collective
         print("OK")
     """)
+    import os
+    import pathlib
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                                          "HOME": "/root",
+                                          "HOME": os.environ.get("HOME", str(repo)),
                                           # pin CPU: with libtpu installed,
                                           # TPU plugin init can block on the
                                           # libtpu lockfile in a bare env
                                           "JAX_PLATFORMS": "cpu"},
-                         cwd="/root/repo")
+                         cwd=repo)
     assert "OK" in out.stdout, out.stdout + out.stderr
 
 

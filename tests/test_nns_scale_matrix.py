@@ -37,6 +37,7 @@ from repro.kernels.streaming_nns import (
     pack_key,
     unpack_key,
 )
+from repro.launch.mesh import make_mesh
 
 WORDS = 2
 K = 16
@@ -104,12 +105,12 @@ def _run(path, queries, db, radius, n_valid):
         return fixed_radius_nns(queries, db, radius, K, scan_block=24,
                                 n_valid=n_valid, superblock=128)
     if path == "sharded":
-        mesh = jax.make_mesh((1,), ("banks",))
+        mesh = make_mesh((1,), ("banks",))
         return sharded_fixed_radius_nns(
             mesh, "banks", queries, db, radius, K, n_valid=n_valid,
             scan_block=16)
     if path == "query_parallel":
-        mesh = jax.make_mesh((1,), ("qp",))
+        mesh = make_mesh((1,), ("qp",))
         return query_parallel_nns(mesh, "qp", queries, db, radius, K,
                                   scan_block=16, n_valid=n_valid)
     raise AssertionError(path)

@@ -13,6 +13,7 @@ from repro.core.nns import (
     sharded_fixed_radius_nns,
 )
 from repro.core.topk import threshold_topk
+from repro.launch.mesh import make_mesh
 
 
 def _sigs(key, n, dim=16, n_bits=128):
@@ -128,7 +129,7 @@ def test_streaming_accepts_arbitrary_db_mask(key):
 
 def test_sharded_matches_unsharded(key):
     """1-device mesh: the sharded path must equal the local path exactly."""
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     x, sigs = _sigs(key, 64)
     q = sigs[:2]
     local = fixed_radius_nns(q, sigs, radius=25, max_candidates=16)
@@ -142,7 +143,7 @@ def test_sharded_matches_unsharded(key):
 
 def test_sharded_composes_with_streaming(key):
     """Sharding over devices + streaming within the shard == dense local."""
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     _, sigs = _sigs(key, 96)
     q = sigs[:3]
     local = fixed_radius_nns(q, sigs, radius=25, max_candidates=16,
@@ -155,7 +156,7 @@ def test_sharded_composes_with_streaming(key):
 def test_query_parallel_matches_local(key):
     """Query-sharded scan (db replicated) == the plain local scan exactly,
     for dense and streaming plans."""
-    mesh = jax.make_mesh((1,), ("qp",))
+    mesh = make_mesh((1,), ("qp",))
     _, sigs = _sigs(key, 80)
     q = sigs[:5]
     for scan_block in (0, 13, None):
@@ -167,7 +168,7 @@ def test_query_parallel_matches_local(key):
 
 
 def test_query_parallel_respects_n_valid(key):
-    mesh = jax.make_mesh((1,), ("qp",))
+    mesh = make_mesh((1,), ("qp",))
     _, sigs = _sigs(key, 64)
     local = fixed_radius_nns(sigs[:3], sigs, radius=25, max_candidates=8,
                              scan_block=16, n_valid=41)
@@ -179,7 +180,7 @@ def test_query_parallel_respects_n_valid(key):
 
 def test_sharded_composes_with_query_axis(key):
     """(query block x bank) 2D partition == the plain local scan."""
-    mesh = jax.make_mesh((1, 1), ("qp", "model"))
+    mesh = make_mesh((1, 1), ("qp", "model"))
     _, sigs = _sigs(key, 96)
     q = sigs[:5]
     local = fixed_radius_nns(q, sigs, radius=25, max_candidates=16,
@@ -205,15 +206,16 @@ def test_query_parallel_multi_device_subprocess():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.nns import (fixed_radius_nns, query_parallel_nns,
                                     sharded_fixed_radius_nns)
+        from repro.launch.mesh import make_mesh
         rng = np.random.default_rng(0)
         sigs = jnp.asarray(rng.integers(0, 2**32, (256, 8), dtype=np.uint32))
         q = sigs[:10]  # 10 % 4 != 0: exercises query padding
         local = fixed_radius_nns(q, sigs, radius=100, max_candidates=16,
                                  scan_block=0)
-        mesh = jax.make_mesh((4,), ("qp",))
+        mesh = make_mesh((4,), ("qp",))
         par = query_parallel_nns(mesh, "qp", q, sigs, radius=100,
                                  max_candidates=16, scan_block=32)
-        mesh2 = jax.make_mesh((4, 2), ("qp", "banks"))
+        mesh2 = make_mesh((4, 2), ("qp", "banks"))
         both = sharded_fixed_radius_nns(mesh2, "banks", q, sigs, radius=100,
                                         max_candidates=16, scan_block=32,
                                         query_axis="qp")

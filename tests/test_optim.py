@@ -9,7 +9,7 @@ from repro.optim.compression import (
     compressed_psum,
     init_error_buffer,
 )
-from repro.utils import shard_map
+from repro.launch.mesh import make_mesh
 
 
 def _rosenbrock_ish(params):
@@ -70,13 +70,13 @@ def test_error_feedback_unbiased_over_time(key):
 
 
 def test_compressed_psum_single_device(key):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x = jax.random.normal(key, (4, 32))
 
     def f(a):
         return compressed_psum(a, "data")
 
-    y = shard_map(f, mesh=mesh,
+    y = jax.shard_map(f, mesh=mesh,
                       in_specs=jax.sharding.PartitionSpec(),
                       out_specs=jax.sharding.PartitionSpec(),
                       check_vma=False)(x)

@@ -124,15 +124,22 @@ _WORDS = st.integers(1, 8)
 
 
 @st.composite
-def _key_pairs(draw):
-    """(words, dist, row) with row hitting the capacity boundaries often."""
-    words = draw(_WORDS)
+def _dist_row(draw, words):
+    """(dist, row) for `words`-word keys, row hitting the capacity
+    boundaries often."""
     cap = max_streamable_items(words)
     dist = draw(st.integers(0, 32 * words))
     row = draw(st.one_of(
         st.integers(0, cap - 1),
         st.sampled_from([0, 1, cap // 2, cap - 2, cap - 1])))
-    return words, dist, row
+    return dist, row
+
+
+@st.composite
+def _key_pairs(draw):
+    """(words, dist, row)."""
+    words = draw(_WORDS)
+    return (words, *draw(_dist_row(words)))
 
 
 @given(_key_pairs())
@@ -146,13 +153,14 @@ def test_key_roundtrip_and_sentinel(pair):
     assert key < 2**31  # stays an int32
 
 
-@given(_key_pairs(), _key_pairs())
-def test_key_total_order_matches_lexicographic(a, b):
+@given(st.data())
+def test_key_total_order_matches_lexicographic(data):
     """key(a) < key(b) iff (dist, row)_a < (dist, row)_b — the packed int32
-    compare IS the dense path's (distance, index) tie-break order."""
-    hypothesis.assume(a[0] == b[0])  # same words -> same encoding
-    words, da, ra = a
-    _, db_, rb = b
+    compare IS the dense path's (distance, index) tie-break order. Both
+    keys share one `words` (one encoding), drawn once."""
+    words = data.draw(_WORDS)
+    da, ra = data.draw(_dist_row(words))
+    db_, rb = data.draw(_dist_row(words))
     assert (pack_key(da, ra, words) < pack_key(db_, rb, words)) == (
         (da, ra) < (db_, rb))
 
