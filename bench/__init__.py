@@ -1,0 +1,1 @@
+"""The benchmark: `python3 bench/run.py --workload <name> ...`."""

@@ -1,0 +1,7 @@
+"""p50_ms: median latency, due time to resolve, over every request due in
+the window; a failed or unresolved request is a miss."""
+from bench import stats
+
+
+def read(ctx):
+    return 1e3 * stats.percentile(stats.latencies_s(ctx.records), 50)
