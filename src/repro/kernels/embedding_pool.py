@@ -88,6 +88,7 @@ def _pool_call(table_t, ids, scales, w, *, block_d, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct((d, bp), jnp.float32),
         interpret=interpret,
+        name="embedding_pool",
     )(ids.reshape(-1), scales.reshape(-1), w.reshape(-1), table_t)
     return out[:, :B]
 

@@ -153,5 +153,6 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qp, kp, vp)
     return out[:, :sq, :]

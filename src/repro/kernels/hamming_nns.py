@@ -61,5 +61,6 @@ def hamming_distances_pallas(
         out_specs=pl.BlockSpec((block_q, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp, np_), jnp.int32),
         interpret=interpret,
+        name="hamming_distances",
     )(queries_p, db_p.T)
     return out[:q, :n]

@@ -86,5 +86,6 @@ def int8_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         interpret=interpret,
+        name="int8_matmul",
     )(xp, wp, sxp, swp)
     return out[:m, :n]

@@ -372,6 +372,7 @@ def streaming_nns_pallas(
             jax.ShapeDtypeStruct((qp, 1), jnp.int32),
         ),
         interpret=interpret,
+        name="streaming_nns",
     )(*operands)
 
     # buffers are sorted: first K slots of each superblock = its best K

@@ -203,6 +203,19 @@ class MetricsRegistry:
         """Append one structured record to the event log (see EventLog)."""
         return self.events.append(kind, **fields)
 
+    def histogram(self, name: str) -> _Hist:
+        """A histogram `name` of its own, merged into every snapshot.
+
+        `observe(value)` on the handle looks nothing up and adds no key to
+        a shared dict, so it is safe from code that runs at any point of
+        another thread's (a `gc.callbacks` hook: `tracing.watch_gc`).
+        """
+        shard = _Shard()
+        hist = shard.hists[name] = _Hist()
+        with self._lock:
+            self._shards.append(shard)
+        return hist
+
     def register_collector(self, fn: Callable) -> None:
         """Register `fn(registry)` to run at the top of every snapshot —
         the bridge from a subsystem's plain-int counters to gauges."""
@@ -211,15 +224,19 @@ class MetricsRegistry:
 
     # -- merged reads ---------------------------------------------------
     def _merged(self) -> tuple[dict, dict]:
-        """(counters, histograms) summed across every thread shard."""
+        """(counters, histograms) summed across every thread shard.
+
+        Each shard's dicts are copied whole before the walk (`list` of a
+        dict's items runs without a thread switch), so a serving thread
+        adding its first key of a name mid-snapshot cannot break it."""
         with self._lock:
             shards = list(self._shards)
         counters: dict[str, float] = {}
         hists: dict[str, _Hist] = {}
         for shard in shards:
-            for k, v in shard.counters.items():
+            for k, v in list(shard.counters.items()):
                 counters[k] = counters.get(k, 0) + v
-            for k, h in shard.hists.items():
+            for k, h in list(shard.hists.items()):
                 m = hists.get(k)
                 if m is None:
                     m = hists[k] = _Hist()

@@ -29,10 +29,22 @@ A chain may be a **subsequence** of `STAGES` (shed: submit/admit/resolve;
 error: submit/admit/resolve) but is always non-empty when tracing is on,
 starts at ``submit``, ends at ``resolve``, and is non-decreasing in time
 (`well_ordered` checks all of it; tested in tests/test_obs.py).
+
+**Phase spans** time the serving thread itself, per cycle and never per
+ticket: `PhaseSpans` tiles a serve loop with the contiguous `PHASES`
+(wait, collect, stack, dispatch, sync, fanout, publish), each both a
+`jax.profiler.TraceAnnotation` ``serving.<phase>`` on the profiler's
+clock, beside the device ops, and one observation of the registry
+histogram ``serving.phase.<phase>_s``. `watch_gc` adds the collector's
+pauses as ``host.gc`` spans and the ``host.gc_pause_s`` histogram.
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import json
+import threading
+import time
 from typing import NamedTuple
 
 # canonical stage order; every span chain's names are a subsequence
@@ -110,3 +122,154 @@ def dump_trace(trace, path) -> int:
             f.write(json.dumps(trace_record(rec), sort_keys=True) + "\n")
             n += 1
     return n
+
+
+# the phases of one serve cycle, in the order the drain thread passes them
+# (docs/OBSERVABILITY.md): blocked on its condition with nothing queued;
+# taking the chunk off the tenant queues; chunk to padded device batch;
+# the stage-program calls; blocked on the device's results; per-ticket
+# results of the ring; per-ticket results of the front end
+PHASES = ("wait", "collect", "stack", "dispatch", "sync", "fanout",
+          "publish")
+
+
+class PhaseSpans:
+    """Contiguous phase spans of one thread's serve loop, on two clocks.
+
+    `enter(phase)` ends the open phase and opens `phase` at the same
+    `time.perf_counter()` instant (and returns it), so the phases of a
+    chain tile it: their durations sum to its wall time. Each phase is a
+    `jax.profiler.TraceAnnotation` named ``serving.<phase>`` and, when it
+    ends, one observation of the histogram ``serving.phase.<phase>_s``.
+    Entering the phase already open continues it.
+
+    `chain()` brackets a run of phases. Chains nest; the outermost ends
+    the open phase when it exits, so a ring's flush inside the drain
+    thread's chain joins the drain cycle, and a caller's own flush stops
+    timing when it returns. One thread owns an instance.
+    """
+
+    def __init__(self, registry):
+        from jax.profiler import TraceAnnotation
+
+        self._registry = registry
+        self._annotation = TraceAnnotation
+        self._labels = {p: f"serving.{p}" for p in PHASES}
+        self._hists = {p: f"serving.phase.{p}_s" for p in PHASES}
+        self._phase = None
+        self._span = None
+        self._t = 0.0
+        self._depth = 0
+
+    def enter(self, phase: str) -> float:
+        """End the open phase and open `phase` now; returns the instant
+        (`time.perf_counter()`) at which the one ended and the other
+        began."""
+        t = time.perf_counter()
+        if phase == self._phase:
+            return t
+        span = self._annotation(self._labels[phase])
+        last, t_last = self._phase, self._t
+        if last is not None:  # close and open back to back on both clocks
+            self._span.__exit__(None, None, None)
+        span.__enter__()
+        self._span, self._phase, self._t = span, phase, t
+        if last is not None:
+            self._registry.observe(self._hists[last], t - t_last)
+        return t
+
+    def _end(self, t: float) -> None:
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._registry.observe(self._hists[self._phase], t - self._t)
+            self._span = self._phase = None
+
+    @contextlib.contextmanager
+    def chain(self):
+        """Bracket a run of phases; the outermost chain ends the open
+        phase on exit (the class docstring)."""
+        self._depth += 1
+        try:
+            yield
+        finally:
+            self._depth -= 1
+            if not self._depth:
+                self._end(time.perf_counter())
+
+
+class _NoPhases:
+    """`PhaseSpans` of an untraced server: times and records nothing."""
+
+    @staticmethod
+    def enter(phase: str) -> float:
+        return 0.0
+
+    @staticmethod
+    def chain():
+        return contextlib.nullcontext()
+
+
+NO_PHASES = _NoPhases()
+
+
+def phase_spans(registry, enabled: bool):
+    """A `PhaseSpans` into `registry`, or `NO_PHASES` when not `enabled`."""
+    return PhaseSpans(registry) if enabled else NO_PHASES
+
+
+# `watch_gc` state: one `gc.callbacks` hook per process, feeding the
+# ``host.gc_pause_s`` histogram of every watching registry
+_gc_lock = threading.Lock()
+_gc_watchers: dict = {}  # id(registry) -> [registry, histogram, users]
+_gc_hists: tuple = ()
+_gc_open = None  # (annotation, perf_counter at "start") of the open pause
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_open
+    if phase == "start":
+        from jax.profiler import TraceAnnotation
+
+        span = TraceAnnotation("host.gc")
+        span.__enter__()
+        _gc_open = (span, time.perf_counter())
+    elif _gc_open is not None:
+        (span, t0), _gc_open = _gc_open, None
+        span.__exit__(None, None, None)
+        pause = time.perf_counter() - t0
+        for hist in _gc_hists:
+            hist.observe(pause)
+
+
+def watch_gc(registry) -> None:
+    """Time the garbage collector's pauses as ``host.gc`` spans and into
+    `registry`'s ``host.gc_pause_s`` histogram, until `unwatch_gc`.
+
+    The `gc.callbacks` hook is installed once per process while any
+    registry watches; a registry watched twice counts each pause once.
+    """
+    global _gc_hists
+    with _gc_lock:
+        entry = _gc_watchers.get(id(registry))
+        if entry is None:  # holding the registry keeps its id unique
+            entry = _gc_watchers[id(registry)] = [
+                registry, registry.histogram("host.gc_pause_s"), 0]
+        entry[2] += 1
+        _gc_hists = tuple(e[1] for e in _gc_watchers.values())
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+
+def unwatch_gc(registry) -> None:
+    """Undo one `watch_gc(registry)`; the hook goes with the last one."""
+    global _gc_hists
+    with _gc_lock:
+        entry = _gc_watchers.get(id(registry))
+        if entry is None:
+            return
+        entry[2] -= 1
+        if not entry[2]:
+            del _gc_watchers[id(registry)]
+        _gc_hists = tuple(e[1] for e in _gc_watchers.values())
+        if not _gc_watchers and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)

@@ -105,9 +105,11 @@ class AsyncServer(MicroBatcher):
     def __init__(self, engine: RecSysEngine, *, max_batch: int = 256,
                  buckets: Sequence[int] | None = None, depth: int = 2,
                  coalesce: int | None = None, trace: bool = True,
-                 registry: MetricsRegistry | None = None):
+                 registry: MetricsRegistry | None = None,
+                 record_tickets: bool = True):
         super().__init__(engine, max_batch=max_batch, buckets=buckets,
-                         trace=trace, registry=registry)
+                         trace=trace, registry=registry,
+                         record_tickets=record_tickets)
         if depth < 1:
             raise ServerConfigError(f"ring depth must be >= 1, got {depth}")
         if coalesce is None:
@@ -133,14 +135,16 @@ class AsyncServer(MicroBatcher):
         Dispatches are non-blocking (JAX async dispatch); the only host
         syncs are the retirements, each overlapped with the following
         buckets' host prep and device compute. Returns with every pending
-        ticket's result materialized, like the synchronous flush.
+        ticket's result materialized, like the synchronous flush. Its
+        phases (stack, dispatch, sync, fanout) tile its wall time.
         """
-        while self._pending:
-            self._ring.append(self._dispatch(self._take_parts()))
-            while len(self._ring) >= self.depth:
+        with self.phases.chain():
+            while self._pending:
+                self._ring.append(self._dispatch(self._take_parts()))
+                while len(self._ring) >= self.depth:
+                    self._retire()
+            while self._ring:
                 self._retire()
-        while self._ring:
-            self._retire()
 
     # ------------------------------------------------------------------
     def _take_parts(self) -> list[tuple[list, int]]:
@@ -162,17 +166,20 @@ class AsyncServer(MicroBatcher):
 
     def _dispatch(self, parts: list[tuple[list, int]]) -> _InFlight:
         """Stack `parts` into one batch and dispatch the staged pipeline."""
-        t_bucket = time.perf_counter() if self.trace else 0.0
+        t_bucket = self.phases.enter("stack")
         stacked = [self._stack_np([q for _, q in chunk], bucket)
                    for chunk, bucket in parts]
         host = (stacked[0] if len(stacked) == 1 else
                 {k: np.concatenate([s[k] for s in stacked])
                  for k in stacked[0]})
         batch = {k: jnp.asarray(v) for k, v in host.items()}
-        u, pooled, self._stats = lookup_step(self.engine, batch, self._stats)
-        nns = scan_step(self.engine, u)
-        items, top, self._stats = rank_stage_step(
-            self.engine, batch, nns.indices, u, pooled, self._stats)
+        self.phases.enter("dispatch")
+        with self._stats_lock:
+            u, pooled, self._stats = lookup_step(self.engine, batch,
+                                                 self._stats)
+            nns = scan_step(self.engine, u)
+            items, top, self._stats = rank_stage_step(
+                self.engine, batch, nns.indices, u, pooled, self._stats)
         for chunk, bucket in parts:
             self.n_served += len(chunk)
             self.n_padded += bucket - len(chunk)
@@ -194,14 +201,12 @@ class AsyncServer(MicroBatcher):
         which is exactly the serialization the ring exists to remove.
         """
         inf = self._ring.popleft()
+        self.phases.enter("sync")
         items = np.asarray(inf.items)  # the one host sync per bucket
         scores = np.asarray(inf.scores)
+        t_sync = self.phases.enter("fanout")
+        tail = ()
         if self.trace:
-            t_sync = time.perf_counter()
-            self.registry.observe("serving.stage.dispatch_s",
-                                  inf.t_dispatch - inf.t_bucket)
-            self.registry.observe("serving.stage.scan_s",
-                                  t_sync - inf.t_dispatch)
             if inf.blocks is not None:
                 bt = np.asarray(inf.blocks)
                 self.registry.count("nns.blocks_touched", int(bt.sum()))
@@ -209,14 +214,11 @@ class AsyncServer(MicroBatcher):
             tail = (("bucket", inf.t_bucket),
                     ("dispatch", inf.t_dispatch),
                     ("scan", t_sync), ("rank", t_sync))
-            for chunk, _ in inf.parts:
-                for ticket, _ in chunk:
-                    self._spans.setdefault(ticket, []).extend(tail)
         row = 0
         for chunk, bucket in inf.parts:
             self._observe(chunk, items[row: row + bucket])
             for j, (ticket, _) in enumerate(chunk):
-                self._resolve(ticket, items[row + j], scores[row + j])
+                self._resolve(ticket, items[row + j], scores[row + j], tail)
             row += bucket
 
     # ------------------------------------------------------------------

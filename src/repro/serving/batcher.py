@@ -33,21 +33,24 @@ Telemetry (docs/OBSERVABILITY.md): with ``trace=True`` (the default)
 every ticket carries a stage-span chain (submit -> admit -> bucket ->
 dispatch -> scan -> rank -> resolve, `repro.obs.tracing.STAGES`) on its
 `ServedQuery.stages` and on the `TicketTrace` records `take_trace()`
-hands back; the per-server `MetricsRegistry` accumulates ticket-latency
-and per-stage histograms plus pruned-scan block counts, and `stats()` is
-a compatibility view over `snapshot()` (`server.stats_view`). The whole
-layer is overhead-gated in benchmarks/obs_overhead.py.
+hands back; a flush's own host time is timed in phases (stack, dispatch,
+sync, fanout: `repro.obs.PhaseSpans`); the per-server `MetricsRegistry`
+accumulates the ticket-latency and phase histograms plus pruned-scan
+block counts, and `stats()` is a compatibility view over `snapshot()`
+(`server.stats_view`), safe to take while another thread serves. The
+whole layer is overhead-gated in benchmarks/obs_overhead.py.
 """
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Sequence
 
 import jax
 import numpy as np
 
-from repro.obs import MetricsRegistry, TicketTrace
+from repro.obs import MetricsRegistry, TicketTrace, phase_spans
 from repro.serving.hot_cache import CacheStats
 from repro.serving.recsys_engine import RecSysEngine, n_summary_blocks, \
     serve_step
@@ -109,7 +112,8 @@ class MicroBatcher:
 
     def __init__(self, engine: RecSysEngine, *, max_batch: int = 256,
                  buckets: Sequence[int] | None = None, trace: bool = True,
-                 registry: MetricsRegistry | None = None):
+                 registry: MetricsRegistry | None = None,
+                 record_tickets: bool = True):
         self.engine = engine
         self.max_batch = max_batch
         self.buckets = tuple(sorted(buckets or default_buckets(max_batch)))
@@ -122,8 +126,12 @@ class MicroBatcher:
         self._results: dict[int, ServedQuery] = {}
         self._next_ticket = 0
         self._closed = False
-        # donated accumulator: hot-cache hits/lookups across every batch
+        # donated accumulator: hot-cache hits/lookups across every batch.
+        # The lock covers each donation and the collector's read, so a
+        # snapshot from another thread never reads a donated array.
         self._stats = CacheStats.zero()
+        self._stats_lock = threading.Lock()
+        self._cache_read = {"hits": 0, "lookups": 0}
         # optional lookup-frequency hook (LiveCatalog.attach wires it to
         # LiveCatalog.observe): called per served chunk with one flat int
         # array of the item ids the batch looked up — history rows and the
@@ -135,12 +143,17 @@ class MicroBatcher:
         self.n_served = 0
         self.n_padded = 0
         self.n_batches = 0
-        # telemetry: stage spans per open ticket + completed-ticket trace
+        # telemetry: submit time per open ticket + completed-ticket trace.
+        # With record_tickets=False an outer front end owns the tickets:
+        # results carry only the bucket..rank stamps, and no TicketTrace
+        # or latency is recorded here.
         self.trace = bool(trace)
+        self.record_tickets = record_tickets
         self.registry = registry if registry is not None \
             else MetricsRegistry()
         self.registry.register_collector(self._collect)
-        self._spans: dict[int, list] = {}
+        self.phases = phase_spans(self.registry, self.trace)
+        self._t_submit: dict[int, float] = {}
         self._trace: list[TicketTrace] = []
         self.n_trace_dropped = 0
 
@@ -179,11 +192,8 @@ class MicroBatcher:
         t = self._per_tenant.setdefault(tenant, {"submitted": 0, "served": 0,
                                                  "shed": 0, "errors": 0})
         t["submitted"] += 1
-        if self.trace:
-            # the synchronous front-ends admit unconditionally: the admit
-            # boundary coincides with submit (no queue to shed from)
-            now = time.perf_counter()
-            self._spans[ticket] = [("submit", now), ("admit", now)]
+        if self.trace and self.record_tickets:
+            self._t_submit[ticket] = time.perf_counter()
         return ticket
 
     def result(self, ticket: int, *,
@@ -214,41 +224,39 @@ class MicroBatcher:
         stage boundaries: an intermediate block on the NNS result marks
         the scan->rank edge (free here — this path blocks on the ranked
         items immediately after anyway), and pruned scans feed their
-        blocks-touched counts into the registry.
+        blocks-touched counts into the registry. The flush's phases
+        (stack, dispatch, sync, fanout) tile its wall time.
         """
-        while self._pending:
-            chunk = self._pending[: self.max_batch]
-            self._pending = self._pending[self.max_batch:]
-            bucket = next(b for b in self.buckets if b >= len(chunk))
-            t_bucket = time.perf_counter() if self.trace else 0.0
-            batch = self._stack([q for _, q in chunk], bucket)
-            items, top, nns, self._stats = serve_step(
-                self.engine, batch, self._stats)
-            if self.trace:
-                t_dispatch = time.perf_counter()
-                jax.block_until_ready(nns.indices)
-                t_scan = time.perf_counter()
-            items = np.asarray(items)
-            scores = np.asarray(top.scores)
-            if self.trace:
-                t_rank = time.perf_counter()
-                self._meter_scan(nns)
-                self.registry.observe("serving.stage.dispatch_s",
-                                      t_dispatch - t_bucket)
-                self.registry.observe("serving.stage.scan_s",
-                                      t_scan - t_dispatch)
-                self.registry.observe("serving.stage.rank_s",
-                                      t_rank - t_scan)
-                tail = (("bucket", t_bucket), ("dispatch", t_dispatch),
-                        ("scan", t_scan), ("rank", t_rank))
-                for ticket, _ in chunk:
-                    self._spans.setdefault(ticket, []).extend(tail)
-            self._observe(chunk, items)
-            for row, (ticket, _) in enumerate(chunk):
-                self._resolve(ticket, items[row], scores[row])
-            self.n_served += len(chunk)
-            self.n_padded += bucket - len(chunk)
-            self.n_batches += 1
+        phases = self.phases
+        with phases.chain():
+            while self._pending:
+                chunk = self._pending[: self.max_batch]
+                self._pending = self._pending[self.max_batch:]
+                bucket = next(b for b in self.buckets if b >= len(chunk))
+                t_bucket = phases.enter("stack")
+                batch = self._stack([q for _, q in chunk], bucket)
+                phases.enter("dispatch")
+                with self._stats_lock:
+                    items, top, nns, self._stats = serve_step(
+                        self.engine, batch, self._stats)
+                t_dispatch = phases.enter("sync")
+                tail = ()
+                if self.trace:
+                    jax.block_until_ready(nns.indices)
+                    t_scan = time.perf_counter()
+                items = np.asarray(items)
+                scores = np.asarray(top.scores)
+                t_rank = phases.enter("fanout")
+                if self.trace:
+                    self._meter_scan(nns)
+                    tail = (("bucket", t_bucket), ("dispatch", t_dispatch),
+                            ("scan", t_scan), ("rank", t_rank))
+                self._observe(chunk, items)
+                for row, (ticket, _) in enumerate(chunk):
+                    self._resolve(ticket, items[row], scores[row], tail)
+                self.n_served += len(chunk)
+                self.n_padded += bucket - len(chunk)
+                self.n_batches += 1
 
     def _observe(self, chunk, items) -> None:
         """Feed the frequency observer one served chunk's item lookups:
@@ -262,26 +270,31 @@ class MicroBatcher:
         served = np.asarray(items[: len(chunk)], np.int64).reshape(-1)
         self.observer(np.concatenate([hist, served]))
 
-    def _resolve(self, ticket: int, items, scores) -> None:
-        """Record one served ticket (+ its tenant accounting + spans)."""
+    def _resolve(self, ticket: int, items, scores, tail: tuple) -> None:
+        """Record one served ticket (+ its tenant accounting + spans);
+        `tail` is its bucket's bucket/dispatch/scan/rank stamps."""
         tenant = self._tenant_of.pop(ticket, 0)
-        stages = self._close_span(ticket, tenant, STATUS_OK)
+        stages = self._close_span(ticket, tenant, STATUS_OK, tail)
         self._results[ticket] = ServedQuery(items=items, scores=scores,
                                             tenant=tenant, stages=stages)
         self._per_tenant[tenant]["served"] += 1
 
-    def _close_span(self, ticket: int, tenant: int, status: str) -> tuple:
-        """Stamp the resolve boundary, record the `TicketTrace`, and feed
-        the latency histograms; returns the finished span chain."""
+    def _close_span(self, ticket: int, tenant: int, status: str,
+                    tail: tuple) -> tuple:
+        """The ticket's finished span chain: submit/admit (the synchronous
+        front-ends admit at submit: no queue to shed from), `tail`, and
+        the resolve boundary, recorded as a `TicketTrace` and a latency
+        observation. Just `tail` when an outer front end owns the ticket."""
         if not self.trace:
             return ()
-        span = self._spans.pop(ticket, None)
-        if not span:
+        if not self.record_tickets:
+            return tail
+        t_sub = self._t_submit.pop(ticket, None)
+        if t_sub is None:
             return ()
         t_res = time.perf_counter()
-        span.append(("resolve", t_res))
-        stages = tuple(span)
-        t_sub = span[0][1]
+        stages = (("submit", t_sub), ("admit", t_sub), *tail,
+                  ("resolve", t_res))
         self._record_trace(
             TicketTrace(ticket, tenant, t_sub, t_res, status, stages))
         self.registry.observe("serving.ticket_latency_s", t_res - t_sub)
@@ -354,8 +367,17 @@ class MicroBatcher:
     def _collect(self, reg: MetricsRegistry) -> None:
         """Snapshot-time collector: publish the plain-int serving counters
         as registry gauges/info (the hot path never touches the registry
-        for these — see docs/OBSERVABILITY.md's overhead contract)."""
-        cache = self._stats.as_dict()
+        for these — see docs/OBSERVABILITY.md's overhead contract).
+
+        The cache counts are read only once the device has computed the
+        newest accumulator, under the lock its donations take; while a
+        bucket is in flight the last counts read stand (exact again once
+        a flush returns), so a snapshot never waits on the device and
+        never reads a donated array."""
+        with self._stats_lock:
+            if all(x.is_ready() for x in self._stats):
+                self._cache_read = self._stats.as_dict()
+            cache = self._cache_read
         reg.info("serving.mode", self.mode)
         reg.info("serving.closed", self._closed)
         reg.gauge("serving.submitted", self._next_ticket)

@@ -40,6 +40,13 @@ and shed accounting. With ``trace=True`` every record (including shed and
 error tickets) carries a stage-span chain: the outer submit/admit stamps,
 the inner ring's bucket/dispatch/scan/rank stamps, and the outer resolve
 — so queue wait shows up as the admit -> bucket gap (docs/OBSERVABILITY.md).
+The outer ticket is the one unit of tracing: the inner ring records no
+trace or latency of its own, and ``serving.ticket_latency_s`` runs from
+the outer submit to the outer resolve. The drain thread's own time is
+tiled by per-cycle phase spans (wait, collect, stack, dispatch, sync,
+fanout, publish; `repro.obs.PhaseSpans`) with ``serving.drain_cycles``
+counting the cycles, and the collector's pauses are ``host.gc`` spans
+(`repro.obs.watch_gc`) while the front end lives.
 """
 from __future__ import annotations
 
@@ -51,7 +58,7 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.obs import MetricsRegistry, TicketTrace
+from repro.obs import MetricsRegistry, TicketTrace, unwatch_gc, watch_gc
 from repro.serving.async_server import AsyncServer
 from repro.serving.batcher import TRACE_CAP, ServedQuery
 from repro.serving.recsys_engine import RecSysEngine
@@ -65,11 +72,6 @@ from repro.serving.server import (
     ServingError,
     stats_view,
 )
-
-# the stages of an inner span chain the outer ticket inherits (the inner
-# submit/admit/resolve stamps are replaced by the outer ticket's own)
-_INNER_STAGES = frozenset(("bucket", "dispatch", "scan", "rank"))
-
 
 class ConcurrentFrontend:
     """Threaded multi-tenant front-end over an inner `AsyncServer` ring.
@@ -90,9 +92,10 @@ class ConcurrentFrontend:
         instead of resolving the ticket as shed (closed-loop callers).
       autostart: start the drain thread at construction (tests pass
         False to stage deterministic overloads, then call `start()`).
-      trace / registry: stage-span tracing + the shared telemetry
-        registry (repro.obs); the inner ring shares the registry, so one
-        `snapshot()` covers the whole front-end.
+      trace / registry: stage-span and phase-span tracing + the shared
+        telemetry registry (repro.obs); the inner ring shares the registry
+        and the drain thread's phase spans, so one `snapshot()` covers the
+        whole front-end.
     """
 
     mode = "concurrent"
@@ -115,7 +118,9 @@ class ConcurrentFrontend:
         self._inner = AsyncServer(engine, max_batch=max_batch,
                                   buckets=buckets, depth=depth,
                                   coalesce=coalesce, trace=trace,
-                                  registry=self.registry)
+                                  registry=self.registry,
+                                  record_tickets=False)
+        self._phases = self._inner.phases  # the drain thread's, shared
         # registered after the inner collector, so the outer view of the
         # shared gauges (submitted/shed/errors/pending/per_tenant) wins
         self.registry.register_collector(self._collect)
@@ -145,6 +150,8 @@ class ConcurrentFrontend:
         self._last_error: str | None = None
         self._thread = threading.Thread(target=self._drain_loop,
                                         name="serving-drain", daemon=True)
+        if self.trace:  # until the drain thread exits
+            watch_gc(self.registry)
         if autostart:
             self.start()
 
@@ -287,54 +294,71 @@ class ConcurrentFrontend:
         return batch
 
     def _drain_loop(self) -> None:
-        while True:
-            with self._cv:
-                self._cv.wait_for(
-                    lambda: self._closed or self._n_queued() > 0)
-                batch = self._collect_locked(self.drain_chunk)
-                if not batch:
-                    if self._closed:
-                        return
-                    continue  # pragma: no cover - spurious wakeup
-                self._n_inflight += len(batch)
-            served = None
-            try:
-                with self._serve_lock:
-                    tickets = [self._inner.submit(q)
-                               for (_, _, q, _) in batch]
-                    self._inner.flush()
-                    served = [self._inner.result(t) for t in tickets]
-                    # the outer ticket is the unit of tracing: its span
-                    # chain absorbs the inner stamps below, so drop the
-                    # inner ring's duplicate trace records
-                    self._inner.take_trace()
-            except ServingError as e:
-                self._contain(e)  # typed: surface through the tickets
-            except Exception as e:  # defensive: the thread must survive
-                self._contain(e)
-            done = time.perf_counter()
-            with self._cv:
-                for i, (ticket, tenant, _, t_sub) in enumerate(batch):
-                    if served is not None:
-                        status = STATUS_OK
-                        chain = self._chain(t_sub, done,
-                                            served[i].stages)
-                        self._results[ticket] = dataclasses.replace(
-                            served[i], tenant=tenant, stages=chain)
-                        self._per_tenant[tenant]["served"] += 1
-                    else:
-                        status = STATUS_ERROR
-                        chain = self._chain(t_sub, done, ())
-                        self._results[ticket] = self._sentinel(
-                            tenant, STATUS_ERROR, chain)
-                        self._per_tenant[tenant]["errors"] += 1
-                    self._record_trace(TicketTrace(
-                        ticket, tenant, t_sub, done, status, chain))
-                    if self.trace:
-                        self.registry.observe("serving.e2e_latency_s",
-                                              done - t_sub)
-                self._n_inflight -= len(batch)
-                self._cv.notify_all()
+        """Serve drain cycles until closed; the gc hook goes with it."""
+        try:
+            with self._phases.chain():
+                while self._drain_cycle():
+                    pass
+        finally:
+            if self.trace:
+                unwatch_gc(self.registry)
+
+    def _drain_cycle(self) -> bool:
+        """Collect, serve and publish one chunk; False once closed with
+        nothing queued. The cycle's phases tile the thread's time: wait,
+        collect, then the inner ring's stack / dispatch / sync / fanout,
+        then publish."""
+        phases = self._phases
+        phases.enter("wait")
+        with self._cv:
+            self._cv.wait_for(lambda: self._closed or self._n_queued() > 0)
+            phases.enter("collect")
+            batch = self._collect_locked(self.drain_chunk)
+            if not batch:
+                return not self._closed
+            self._n_inflight += len(batch)
+        phases.enter("stack")
+        served = None
+        try:
+            with self._serve_lock:
+                tickets = [self._inner.submit(q) for (_, _, q, _) in batch]
+                self._inner.flush()
+                served = [self._inner.result(t) for t in tickets]
+        except ServingError as e:
+            self._contain(e)  # typed: surface through the tickets
+        except Exception as e:  # defensive: the thread must survive
+            self._contain(e)
+        phases.enter("publish")
+        self._publish(batch, served, time.perf_counter())
+        return True
+
+    def _publish(self, batch: list, served: list | None,
+                 done: float) -> None:
+        """Resolve one drained chunk's outer tickets (``served`` None: the
+        chunk failed, every ticket resolves as an error)."""
+        with self._cv:
+            for i, (ticket, tenant, _, t_sub) in enumerate(batch):
+                if served is not None:
+                    status = STATUS_OK
+                    chain = self._chain(t_sub, done, served[i].stages)
+                    self._results[ticket] = dataclasses.replace(
+                        served[i], tenant=tenant, stages=chain)
+                    self._per_tenant[tenant]["served"] += 1
+                else:
+                    status = STATUS_ERROR
+                    chain = self._chain(t_sub, done, ())
+                    self._results[ticket] = self._sentinel(
+                        tenant, STATUS_ERROR, chain)
+                    self._per_tenant[tenant]["errors"] += 1
+                self._record_trace(TicketTrace(
+                    ticket, tenant, t_sub, done, status, chain))
+                if self.trace:
+                    self.registry.observe("serving.ticket_latency_s",
+                                          done - t_sub)
+            self._n_inflight -= len(batch)
+            self._cv.notify_all()
+        if self.trace:
+            self.registry.count("serving.drain_cycles")
 
     def _chain(self, t_sub: float, done: float, inner: tuple) -> tuple:
         """The outer ticket's span chain: outer submit/admit stamps, the
@@ -343,8 +367,7 @@ class ConcurrentFrontend:
         the degenerate submit -> admit -> resolve chain."""
         if not self.trace:
             return ()
-        mid = tuple((s, t) for s, t in inner if s in _INNER_STAGES)
-        return (("submit", t_sub), ("admit", t_sub), *mid,
+        return (("submit", t_sub), ("admit", t_sub), *inner,
                 ("resolve", done))
 
     def _contain(self, exc: Exception) -> None:
@@ -355,9 +378,6 @@ class ConcurrentFrontend:
             self._inner._pending = []
             self._inner._ring.clear()
             self._inner._results.clear()
-            spans = getattr(self._inner, "_spans", None)
-            if spans is not None:  # tests inject span-less fake inners
-                spans.clear()
 
     # ------------------------------------------------------------------
     # engine swaps / stats / trace

@@ -481,8 +481,9 @@ def _lookup_stage(engine: RecSysEngine, batch: dict, stats: CacheStats):
     (both needed again by the ranking stage), and the donated hot-cache
     accumulator advanced by this batch's feature lookups.
     """
-    u, pooled, st = _features(engine, batch)
-    return u, pooled, stats + st
+    with jax.named_scope("lookup"):
+        u, pooled, st = _features(engine, batch)
+        return u, pooled, stats + st
 
 
 def _scan_stage(engine: RecSysEngine, u: jax.Array) -> NNSResult:
@@ -493,7 +494,10 @@ def _scan_stage(engine: RecSysEngine, u: jax.Array) -> NNSResult:
     of (engine, u): no batch dict, no cache counters — so a caller can keep
     bucket i's scan in flight while bucket i+1 runs `lookup_step`.
     """
-    return _nns(engine, lsh_signature(u, engine.lsh_proj))
+    with jax.named_scope("lsh_sign"):
+        q_sigs = lsh_signature(u, engine.lsh_proj)
+    with jax.named_scope("scan"):
+        return _nns(engine, q_sigs)
 
 
 def _rank_stage(engine: RecSysEngine, batch: dict, cand: jax.Array,
@@ -504,12 +508,12 @@ def _rank_stage(engine: RecSysEngine, batch: dict, cand: jax.Array,
     (final_items, topk, stats') exactly like the tail of `serve_step`.
     Composing the three stages bit-matches the fused step (tested).
     """
-    top, st = _rank(engine, batch, cand, u, pooled)
-    final = jnp.where(top.indices >= 0,
-                      jnp.take_along_axis(cand, jnp.maximum(top.indices, 0),
-                                          1),
-                      -1)
-    return final, top, stats + st
+    with jax.named_scope("rank"):
+        top, st = _rank(engine, batch, cand, u, pooled)
+        final = jnp.where(
+            top.indices >= 0,
+            jnp.take_along_axis(cand, jnp.maximum(top.indices, 0), 1), -1)
+        return final, top, stats + st
 
 
 filter_step = jax.jit(_filter_step)

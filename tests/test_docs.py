@@ -129,6 +129,14 @@ def _public_api():
         ("ConcurrentFrontend.stats", ConcurrentFrontend.stats),
         ("ConcurrentFrontend.swap_engine", ConcurrentFrontend.swap_engine),
         ("ConcurrentFrontend.take_trace", ConcurrentFrontend.take_trace),
+        # the serving thread's phase spans + the collector's pauses
+        ("PhaseSpans", obs.PhaseSpans),
+        ("PhaseSpans.enter", obs.PhaseSpans.enter),
+        ("PhaseSpans.chain", obs.PhaseSpans.chain),
+        ("phase_spans", obs.phase_spans),
+        ("watch_gc", obs.watch_gc),
+        ("unwatch_gc", obs.unwatch_gc),
+        ("MetricsRegistry.histogram", obs.MetricsRegistry.histogram),
         ("LoadGen", LoadGen),
         ("LoadGen.schedule", LoadGen.schedule),
         ("LoadGen.replay", LoadGen.replay),

@@ -15,7 +15,10 @@ Three contracts (docs/OBSERVABILITY.md):
     three modes, and BENCH artifacts' embedded telemetry passes
     `bench_io.check_telemetry_schema`.
 """
+import gc
+import sys
 import threading
+import time
 from collections import deque
 
 import jax
@@ -26,9 +29,11 @@ from repro.data import synthetic
 from repro.data.synthetic import serving_queries as _queries
 from repro.models import recsys as rs
 from repro.obs import (
+    PHASES,
     STAGES,
     EventLog,
     MetricsRegistry,
+    PhaseSpans,
     TicketTrace,
     bucket_upper_bounds,
     dump_trace,
@@ -269,6 +274,10 @@ def test_trace_false_disables_spans(served, mode):
         assert trace == []
     snap = server.snapshot()
     assert snap.get("serving.ticket_latency_s.count", 0) == 0
+    # the phase spans cost nothing and record nothing either
+    assert not [k for k in snap if k.startswith(("serving.phase.",
+                                                  "host.gc"))]
+    assert "serving.drain_cycles" not in snap
     server.close()
 
 
@@ -401,11 +410,16 @@ def test_snapshot_covers_serving_and_stage_histograms(served, mode):
     assert snap["serving.mode"] == mode
     assert snap["serving.served"] == n
     assert snap["serving.ticket_latency_s.count"] == n
-    assert snap["serving.stage.dispatch_s.count"] >= 1
+    for phase in ("stack", "dispatch", "sync", "fanout"):
+        assert snap[f"serving.phase.{phase}_s.count"] >= 1
     assert snap["serving.ticket_latency_s.mean"] > 0
     assert snap["cache.lookups"] > 0
     if mode == "concurrent":
-        assert snap["serving.e2e_latency_s.count"] == n
+        # one latency a ticket, from the outer chain: queue wait included
+        trace = server.take_trace()
+        assert snap["serving.ticket_latency_s.sum"] == pytest.approx(
+            sum(r.latency_s for r in trace))
+        assert snap["serving.drain_cycles"] >= 1
         assert snap["serving.per_tenant"][0]["served"] == n
     from benchmarks.bench_io import check_telemetry_schema
     check_telemetry_schema(snap, required=("serving.served",
@@ -424,3 +438,190 @@ def test_shared_registry_spans_servers(served):
     server.serve_many(_stream(data, 3))
     assert reg.snapshot()["serving.served"] == 3
     server.close()
+
+
+# ---------------------------------------------------------------------------
+# phase spans of the serving thread, the collector's pauses, and a snapshot
+# taken while the drain thread serves
+# ---------------------------------------------------------------------------
+def _phase_sums(snap) -> dict:
+    return {p: snap.get(f"serving.phase.{p}_s.sum", 0.0) for p in PHASES}
+
+
+def test_phase_spans_tile_a_chain_and_nest():
+    """enter() ends one phase where the next begins; re-entering the open
+    phase continues it; only the outermost chain ends the open phase."""
+    reg = MetricsRegistry()
+    phases = PhaseSpans(reg)
+    with phases.chain():
+        t0 = phases.enter("wait")
+        t1 = phases.enter("stack")
+        assert phases.enter("stack") >= t1  # continues, no new span
+        with phases.chain():  # a nested flush joins the outer chain
+            t2 = phases.enter("dispatch")
+        assert reg.snapshot().get("serving.phase.dispatch_s.count", 0) == 0
+        t3 = phases.enter("publish")
+    snap = reg.snapshot()
+    assert snap["serving.phase.wait_s.sum"] == pytest.approx(t1 - t0)
+    assert snap["serving.phase.stack_s.count"] == 1
+    assert snap["serving.phase.stack_s.sum"] == pytest.approx(t2 - t1)
+    assert snap["serving.phase.dispatch_s.sum"] == pytest.approx(t3 - t2)
+    assert snap["serving.phase.publish_s.count"] == 1
+    assert snap["serving.phase.publish_s.sum"] > 0
+
+
+def test_drain_phases_cover_the_drain_threads_wall_time(served):
+    """A served stream: the drain thread's phases (wait, collect, stack,
+    dispatch, sync, fanout, publish) sum to its wall time within 2 %, and
+    every cycle is counted."""
+    engine, data = served[:2]
+    server = _make(engine, "concurrent", autostart=False)
+    wall = {}
+    loop = server._drain_loop
+
+    def timed_loop():
+        wall["start"] = time.perf_counter()
+        loop()
+        wall["end"] = time.perf_counter()
+
+    server._thread = threading.Thread(target=timed_loop, daemon=True)
+    server.start()
+    tickets = []
+    for wave in range(8):
+        tickets += [server.submit(q, tenant=i % 2)
+                    for i, q in enumerate(_stream(data, 11))]
+        time.sleep(0.03)
+    server.close()
+    assert all(server.result(t).ok for t in tickets)
+    snap = server.snapshot()
+    sums = _phase_sums(snap)
+    assert all(sums[p] > 0 for p in PHASES), sums
+    span = wall["end"] - wall["start"]
+    assert sum(sums.values()) == pytest.approx(span, rel=0.02)
+    cycles = snap["serving.drain_cycles"]
+    assert cycles == snap["serving.phase.publish_s.count"] and cycles >= 8
+
+
+def test_profiler_trace_holds_the_drain_phases_and_gc(served, tmp_path):
+    """On the profiler's clock: a CPU trace of a concurrent server holds
+    the serving.<phase> annotations, and a collection under a traced
+    front end is a host.gc span and a host.gc_pause_s observation."""
+    engine, data = served[:2]
+    server = _make(engine, "concurrent")
+    server.serve_many(_stream(data, 5))  # compiled before the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        server.serve_many(_stream(data))
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    server.close()
+    assert server.snapshot()["host.gc_pause_s.count"] >= 1
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    names = {e.name for plane in pd.planes if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events}
+    assert {f"serving.{p}" for p in PHASES} <= names, sorted(names)[:40]
+    assert "host.gc" in names
+
+
+def test_gc_hook_lives_with_traced_frontends(served):
+    """One gc.callbacks hook a process, installed while a traced front
+    end lives; two front ends on one registry count a pause once."""
+    from repro.obs import tracing
+
+    engine = served[0]
+    reg = MetricsRegistry()
+    gc.disable()  # only the collections below
+    try:
+        a = _make(engine, "concurrent", registry=reg)
+        b = _make(engine, "concurrent", registry=reg)
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        gc.collect()
+        assert reg.snapshot()["host.gc_pause_s.count"] == 1
+        a.close()
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        b.close()
+        b.close()  # idempotent: the hook goes once
+        assert tracing._on_gc not in gc.callbacks
+        gc.collect()
+        assert reg.snapshot()["host.gc_pause_s.count"] == 1
+    finally:
+        gc.enable()
+
+
+def test_snapshot_while_serving_raises_nothing(served):
+    """A thread snapshots in a loop while the drain thread serves (and
+    donates the cache-stats accumulator): no error, and the final counts
+    match a server nobody watched."""
+    engine, data = served[:2]
+    stream = _stream(data, 40)
+
+    def serve(server):
+        for i in range(4):
+            server.serve_many(stream[i::4], tenant=i % 2)
+
+    quiet = _make(engine, "concurrent")
+    serve(quiet)
+    want = quiet.snapshot()
+    quiet.close()
+
+    server = _make(engine, "concurrent")
+    stop, errors, n_snaps = threading.Event(), [], [0]
+
+    def scrape():
+        while not stop.is_set():
+            try:
+                server.snapshot()
+                server.registry.to_prometheus()
+                n_snaps[0] += 1
+            except Exception as e:  # noqa: BLE001 - the test's finding
+                errors.append(e)
+                return
+            time.sleep(0.001)
+
+    scraper = threading.Thread(target=scrape)
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # more interleavings of the three threads
+    scraper.start()
+    try:
+        serve(server)
+    finally:
+        stop.set()
+        scraper.join(timeout=60)
+        sys.setswitchinterval(switch)
+    assert not scraper.is_alive()
+    assert not errors, errors
+    assert n_snaps[0] > 0
+    got = server.snapshot()
+    for key in ("serving.served", "serving.padded", "cache.hits",
+                "cache.lookups", "serving.errors"):
+        assert got[key] == want[key], key
+    server.close()
+
+
+@pytest.mark.parametrize("step,scopes", [
+    ("lookup_step", ("lookup",)), ("scan_step", ("lsh_sign", "scan")),
+    ("rank_stage_step", ("rank",))])
+def test_stage_programs_carry_their_named_scopes(served, step, scopes):
+    """The device work is named: each staged program's ops sit under its
+    `jax.named_scope`, inside the jitted function the trace reduction
+    matches by name (`_lookup_stage`, `_scan_stage`, `_rank_stage`)."""
+    from repro.serving import recsys_engine as rse
+    from repro.serving.hot_cache import CacheStats
+
+    engine, data = served[:2]
+    server = _make(engine, "pipelined")
+    batch = {k: jax.numpy.asarray(v) for k, v in
+             server._stack_np(_stream(data, 3), 4).items()}
+    u, pooled, _ = rse.lookup_step(engine, batch, CacheStats.zero())
+    cand = rse.scan_step(engine, u).indices
+    args = {"lookup_step": (engine, batch, CacheStats.zero()),
+            "scan_step": (engine, u),
+            "rank_stage_step": (engine, batch, cand, u, pooled,
+                                CacheStats.zero())}[step]
+    text = getattr(rse, step).lower(*args).as_text(debug_info=True)
+    fn = {"lookup_step": "_lookup_stage", "scan_step": "_scan_stage",
+          "rank_stage_step": "_rank_stage"}[step]
+    for scope in scopes:
+        assert f"jit({fn})/{scope}/" in text, scope

@@ -93,6 +93,7 @@ def test_streaming_nns_compiles_two_superblocks(one_chip, pallas, masked,
             prune_block_rows=SUMMARY_ROWS if pruned else None)
 
     compiled = _compile(scan, *specs)
+    assert "%streaming_nns." in compiled.as_text()  # the kernel's name=
     mem = compiled.memory_analysis()
     # the DB is read in place: no relayout copy of the 256 MiB table
     assert mem.temp_size_in_bytes < CATALOG * WORDS * 4 // 8
@@ -101,17 +102,19 @@ def test_streaming_nns_compiles_two_superblocks(one_chip, pallas, masked,
 @pytest.mark.parametrize("slots", [1, 20])
 def test_embedding_pool_compiles(one_chip, pallas, slots):
     n, d = 3883, 32
-    _compile(ops.embedding_pool,
-             _spec(one_chip, (n, d), jnp.int8),
-             _spec(one_chip, (n, 1), jnp.float32),
-             _spec(one_chip, (QUERIES, slots), jnp.int32),
-             _spec(one_chip, (QUERIES, slots), jnp.float32))
+    compiled = _compile(ops.embedding_pool,
+                        _spec(one_chip, (n, d), jnp.int8),
+                        _spec(one_chip, (n, 1), jnp.float32),
+                        _spec(one_chip, (QUERIES, slots), jnp.int32),
+                        _spec(one_chip, (QUERIES, slots), jnp.float32))
+    assert "%embedding_pool." in compiled.as_text()
 
 
 def test_hamming_distances_compiles(one_chip, pallas):
-    _compile(ops.hamming_distances,
-             _spec(one_chip, (QUERIES, WORDS), jnp.uint32),
-             _spec(one_chip, (3000, WORDS), jnp.uint32))
+    compiled = _compile(ops.hamming_distances,
+                        _spec(one_chip, (QUERIES, WORDS), jnp.uint32),
+                        _spec(one_chip, (3000, WORDS), jnp.uint32))
+    assert "%hamming_distances." in compiled.as_text()
 
 
 def test_paper_serve_step_compiles(one_chip, pallas):
