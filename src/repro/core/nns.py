@@ -48,7 +48,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
-from repro.kernels.streaming_nns import BIG_DIST
+from repro.kernels.streaming_nns import BIG_DIST, SCAN_STATS
 from repro.utils import cdiv, pytree_dataclass
 
 # invalid-slot distance sentinel (single definition in
@@ -73,6 +73,18 @@ class NNSResult(NamedTuple):
     # (q,) int32 — summary blocks whose lower bound admitted the query, or
     # None when the scan ran unpruned (dense plan, no summary, prune=False)
     blocks_touched: jax.Array | None = None
+    # () int32 — the streaming kernel's (query-tile, db-block) cells
+    # scanned, cells whose candidate merge ran, and merge rounds run; None
+    # where the plan ran no kernel merge (dense, ref backend, out-of-core,
+    # sharded and query-parallel plans)
+    scan_cells: jax.Array | None = None
+    merge_cells: jax.Array | None = None
+    merge_rounds: jax.Array | None = None
+
+
+# the optional counters of a scan's result, after its three outputs: the
+# servers write each one that a plan fills as `nns.<field>`
+NNS_COUNTERS = NNSResult._fields[3:]
 
 
 # ---------------------------------------------------------------------------
@@ -344,13 +356,14 @@ def fixed_radius_nns(
             prune_blocks, blocks_touched = _prune_mask(
                 query_sigs, summary, radius)
             block_rows = summary.block_rows
-        indices, distances, counts = ops.streaming_nns(
+        indices, distances, counts, stats = ops.streaming_nns(
             query_sigs, db_sigs, radius=radius,
             max_candidates=max_candidates, scan_block=block, n_valid=n_valid,
             superblock=superblock, db_mask=db_mask,
             prune_blocks=prune_blocks, prune_block_rows=block_rows)
+        cells = {} if stats is None else dict(zip(SCAN_STATS, stats))
         return NNSResult(indices=indices, distances=distances, counts=counts,
-                         blocks_touched=blocks_touched)
+                         blocks_touched=blocks_touched, **cells)
 
     d = ops.hamming_distances(query_sigs, db_sigs)  # (q, n)
     within = d <= radius
@@ -645,11 +658,14 @@ def query_parallel_nns(
         mask_local = rest.pop(0) if db_mask is not None else None
         sum_local = (BlockSummary(*rest, block_rows=summary.block_rows)
                      if use_prune else None)
-        return fixed_radius_nns(q_local, db_local, radius, max_candidates,
-                                scan_block=scan_block, n_valid=nv_local,
-                                superblock=superblock, db_mask=mask_local,
-                                summary=sum_local,
-                                prune=True if use_prune else False)
+        res = fixed_radius_nns(q_local, db_local, radius, max_candidates,
+                               scan_block=scan_block, n_valid=nv_local,
+                               superblock=superblock, db_mask=mask_local,
+                               summary=sum_local,
+                               prune=True if use_prune else False)
+        # the kernel's counters stay per device: this plan reports none
+        return res._replace(scan_cells=None, merge_cells=None,
+                            merge_rounds=None)
 
     q_spec = P(query_axis)
     specs_in = (q_spec, P(), P())
@@ -736,10 +752,10 @@ def merge_delta_candidates(base: NNSResult, delta: NNSResult,
     dist = jnp.take_along_axis(dist, order, axis=1)
     idx, d = merge_candidate_buffers(ids, dist, max_candidates)
     # the bounded delta scans dense (never pruned): the merged result
-    # carries the base scan's blocks_touched counter through unchanged
-    return NNSResult(indices=idx, distances=d,
-                     counts=base.counts + delta.counts,
-                     blocks_touched=base.blocks_touched)
+    # carries the base scan's counters (blocks_touched, the kernel's
+    # cells and rounds) through unchanged
+    return base._replace(indices=idx, distances=d,
+                         counts=base.counts + delta.counts)
 
 
 def query_parallel_delta_scan(

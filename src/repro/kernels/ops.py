@@ -127,10 +127,11 @@ def _flash_attention_pallas(q, k, v, *, causal=True, scale=None, interpret):
 def _streaming_nns_ref(queries, db, *, radius, max_candidates, scan_block,
                        n_valid, superblock=None, db_mask=None,
                        prune_blocks=None, prune_block_rows=None):
-    return ref.streaming_nns_ref(
+    # the oracle has no merge to count: no stats
+    return (*ref.streaming_nns_ref(
         queries, db, radius, max_candidates, scan_block=scan_block,
         n_valid=n_valid, superblock=superblock, db_mask=db_mask,
-        prune_blocks=prune_blocks, prune_block_rows=prune_block_rows)
+        prune_blocks=prune_blocks, prune_block_rows=prune_block_rows), None)
 
 
 # the kernel's merge holds (block_q, k_pad + block_n) int32 keys per round,
@@ -200,8 +201,11 @@ def streaming_nns(queries, db, *, radius, max_candidates,
                   db_mask=None, prune_blocks=None, prune_block_rows=None):
     """Streaming fixed-radius NNS over the full DB, O(q*max_candidates) mem.
 
-    Returns (indices, distances, counts) bit-matching the dense
-    hamming_distances -> threshold -> top_k path; `n_valid` (dynamic ok)
+    Returns (indices, distances, counts, stats): the first three
+    bit-match the dense hamming_distances -> threshold -> top_k path;
+    `stats` is the kernel's (3,) int32 engagement counters in
+    `streaming_nns.SCAN_STATS` order, None on the ref backend, which has
+    no kernel merge to count. `n_valid` (dynamic ok)
     masks trailing padding rows, `scan_block` sets the scan chunk size.
     DBs beyond the packed-key capacity (4.19M rows at 256-bit signatures)
     scan as multiple superblocks transparently; `superblock` shrinks the
@@ -278,7 +282,7 @@ def _outofcore_chunk_scan(queries, chunk, n_rows, db_mask, row_map, *,
     streaming dispatch plus the local->global row remap (row_map is the
     monotonically increasing gather index, so the remap preserves the
     buffer's (distance, row) sort order)."""
-    idx, dist, counts = dispatch(
+    idx, dist, counts, _ = dispatch(
         "streaming_nns", queries, chunk, radius=radius,
         max_candidates=max_candidates, scan_block=scan_block,
         n_valid=n_rows, db_mask=db_mask)

@@ -34,14 +34,23 @@ superblock row ranges are disjoint and ascending, so stability alone
 reproduces the exact global (distance, index) order. DB capacity becomes
 int32 row ids (2**31 rows) rather than `2**shift`.
 
-The per-block merge keeps the buffer sorted: `max_candidates` rounds each
-take every query row's current minimum over the resident buffer and the
-block's candidate keys into the next buffer slot and retire that key
-(valid keys are unique, so exactly one leaves per round). Every value is a
-2-D (block_q, lanes) array — lane reductions and selects that Mosaic
-lowers on v5e, with no in-kernel sort, reshape or 3-D broadcast. Blocks
-with no matches (the common case at selective radii) skip the merge
-entirely under `pl.when`.
+The per-block merge costs what enters the buffer, not `max_candidates`.
+The buffer is sorted by key, so each query row's `max_candidates`-th slot
+holds its K-th key (`big` while the buffer is not full), and a block's key
+can enter only if it is below that; keys are unique and rows arrive in
+ascending order, so a later row at the K-th distance never enters. Blocks
+where no row has an entering key skip the merge under `pl.when`. Otherwise,
+with `m` the most entering keys of any row of the tile, the merge runs
+`min(m, max_candidates)` insertion rounds: each takes every row's smallest
+entering key, finds its slot by counting the buffer keys below it, writes
+it there and shifts the tail one lane right (`pltpu.roll`); a row with
+nothing left inserts `big`, which changes nothing. After K rounds a row's
+K smallest entering keys are in, and any key left out is above K buffered
+keys, so it could never reach the top K. Every value is a 2-D
+(block_q, lanes) array — lane reductions, rolls and selects that Mosaic
+lowers on v5e, with no in-kernel sort, reshape or 3-D broadcast. A small
+resident stats tile per query tile counts the cells scanned, the cells
+whose merge ran and the insertion rounds run.
 
 Grid: (q_blocks, n_blocks) with the DB dimension innermost and *sequential*
 — the (1, block_q, K) candidate tile is revisited across its superblock's
@@ -76,6 +85,9 @@ from repro.utils import cdiv, round_up
 # kernels/ref.py (oracle decode) both import it, so the bit-match invariant
 # between every path hangs off this one definition.
 BIG_DIST = 2**30
+
+# the kernel's engagement counters, in the order of its `stats` output
+SCAN_STATS = ("scan_cells", "merge_cells", "merge_rounds")
 
 
 def key_shift(words: int) -> int:
@@ -180,32 +192,34 @@ def block_distances(q, db_t):
     return d
 
 
-def _merge_smallest(buf, new_keys, *, n_keep, big):
-    """Sorted `n_keep` smallest keys of `buf` (bq, k_pad) + `new_keys`.
+def _insert_keys(buf, new_keys, rounds, *, big):
+    """Insert each row's `rounds` (traced) smallest keys of `new_keys`
+    (`big` = none) into the sorted buffer `buf` (bq, k_pad).
 
-    Valid keys are unique (distinct rows), so each round takes every
-    row's current minimum into the next slot and retires exactly that
-    key; slots past the valid keys (and past `n_keep`) stay `big`.
+    A round finds each row's slot by counting the buffer keys below its
+    smallest remaining key, writes the key there, shifts the tail one lane
+    right and retires the key; a row with no key left inserts `big`, a
+    no-op. Keys are unique, so the buffer stays sorted; the keys pushed
+    past slot `n_keep - 1` are never read back.
     """
     slot = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 1)
 
-    def take_min(k, carry):
-        buf, new, out = carry
-        mn = jnp.minimum(jnp.min(buf, axis=1, keepdims=True),
-                         jnp.min(new, axis=1, keepdims=True))
-        out = jnp.where(slot == k, mn, out)
-        return (jnp.where(buf == mn, big, buf),
-                jnp.where(new == mn, big, new), out)
+    def insert(_, carry):
+        buf, new = carry
+        key = jnp.min(new, axis=1, keepdims=True)
+        pos = jnp.sum((buf < key).astype(jnp.int32), axis=1, keepdims=True)
+        tail = pltpu.roll(buf, 1, 1)  # lane s holds slot s - 1
+        buf = jnp.where(slot < pos, buf, jnp.where(slot == pos, key, tail))
+        return buf, jnp.where(new == key, big, new)
 
-    out = jnp.full(buf.shape, big, jnp.int32)
-    return jax.lax.fori_loop(0, n_keep, take_min, (buf, new_keys, out))[2]
+    return jax.lax.fori_loop(0, rounds, insert, (buf, new_keys))[0]
 
 
 def _streaming_nns_kernel(limit_ref, q_ref, db_ref, *refs, radius, shift,
                           big, blocks_per_sb, n_keep, has_mask, has_prune):
     mask_ref = refs[0] if has_mask else None
     scan_ref = refs[int(has_mask)] if has_prune else None
-    keys_ref, counts_ref = refs[-2:]
+    keys_ref, counts_ref, stats_ref = refs[-3:]
     j = pl.program_id(1)
 
     # buffer inits stay OUTSIDE the prune predicate: a superblock whose
@@ -218,6 +232,7 @@ def _streaming_nns_kernel(limit_ref, q_ref, db_ref, *refs, radius, shift,
     @pl.when(j == 0)
     def _init_counts():
         counts_ref[...] = jnp.zeros(counts_ref.shape, jnp.int32)
+        stats_ref[...] = jnp.zeros(stats_ref.shape, jnp.int32)
 
     def _scan_block():
         d = block_distances(q_ref[...], db_ref[...])  # (block_q, block_n)
@@ -227,16 +242,28 @@ def _streaming_nns_kernel(limit_ref, q_ref, db_ref, *refs, radius, shift,
         within = jnp.logical_and(d <= radius, gidx < limit_ref[0])
         if mask_ref is not None:  # tombstoned rows never match (matchline AND)
             within = jnp.logical_and(within, mask_ref[...] != 0)
-        hits = within.astype(jnp.int32)
-        counts_ref[...] += jnp.sum(hits, axis=1, keepdims=True)
+        counts_ref[...] += jnp.sum(within.astype(jnp.int32), axis=1,
+                                   keepdims=True)
 
-        @pl.when(jnp.max(hits) > 0)
-        def _merge():
-            # row bits carry the superblock-LOCAL offset: int32 keys
-            lidx = (j % blocks_per_sb) * block_n + iota
-            new_keys = jnp.where(within, d * (1 << shift) + lidx, big)
-            keys_ref[0] = _merge_smallest(keys_ref[0], new_keys,
-                                          n_keep=n_keep, big=big)
+        # row bits carry the superblock-LOCAL offset: int32 keys
+        keys = d * (1 << shift) + (j % blocks_per_sb) * block_n + iota
+        buf = keys_ref[0]
+        kth = buf[:, n_keep - 1:n_keep]  # `big` until the buffer is full
+        enter = jnp.logical_and(within, keys < kth)
+        m = jnp.max(jnp.sum(enter.astype(jnp.int32), axis=1))
+        new_keys = jnp.where(enter, keys, big)
+
+        rounds = jnp.minimum(m, n_keep)
+
+        @pl.when(m > 0)
+        def _insert():
+            keys_ref[0] = _insert_keys(buf, new_keys, rounds, big=big)
+
+        # lanes 0, 1, 2: cells scanned, cells merged, merge rounds
+        lane = jax.lax.broadcasted_iota(jnp.int32, stats_ref.shape, 2)
+        stats_ref[...] += jnp.where(
+            lane == 0, 1, jnp.where(lane == 1, (m > 0).astype(jnp.int32),
+                                    jnp.where(lane == 2, rounds, 0)))
 
     if scan_ref is None:
         _scan_block()
@@ -268,11 +295,14 @@ def streaming_nns_pallas(
     prune_block_rows: int | None = None,  # rows per summary block
     interpret: bool = False,
 ):
-    """Streaming fixed-radius NNS -> (indices, distances, counts).
+    """Streaming fixed-radius NNS -> (indices, distances, counts, stats).
 
     Bit-matches the dense hamming->threshold->top_k path: indices/distances
     are the `max_candidates` nearest matches sorted by (distance, index),
     padded with (-1, BIG_DIST); counts are total matches within radius.
+    `stats` is (3,) int32 over the whole grid, in `SCAN_STATS` order: the
+    (query-tile, db-block) cells scanned, the cells whose merge ran, and
+    the insertion rounds run (see module docstring).
     DBs larger than the packed-key capacity scan as multiple superblocks
     whose candidate buffers are merged host-side (see module docstring).
     `db_mask` marks per-row eligibility (tombstones); None scans unmasked
@@ -355,21 +385,24 @@ def streaming_nns_pallas(
         _streaming_nns_kernel, radius=radius, shift=shift, big=big,
         blocks_per_sb=blocks_per_sb, n_keep=max_candidates,
         has_mask=db_mask is not None, has_prune=prune_blocks is not None)
-    keys, counts = pl.pallas_call(
+    q_tiles = qp // block_q
+    keys, counts, stats = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(qp // block_q, n_blocks),
+            grid=(q_tiles, n_blocks),
             in_specs=in_specs,
             out_specs=(
                 pl.BlockSpec((1, block_q, k_pad),
                              lambda i, j, lim: (j // blocks_per_sb, i, 0)),
                 pl.BlockSpec((block_q, 1), lambda i, j, lim: (i, 0)),
+                pl.BlockSpec((1, 8, 128), lambda i, j, lim: (i, 0, 0)),
             ),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((n_sb, qp, k_pad), jnp.int32),
             jax.ShapeDtypeStruct((qp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((q_tiles, 8, 128), jnp.int32),
         ),
         interpret=interpret,
         name="streaming_nns",
@@ -389,4 +422,5 @@ def streaming_nns_pallas(
             indices, distances, max_candidates)
     else:
         indices, distances = indices[0], distances[0]
-    return indices, distances, counts[:q, 0]
+    stats = jnp.sum(stats[:, 0, :len(SCAN_STATS)], axis=0)
+    return indices, distances, counts[:q, 0], stats

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import MetricsRegistry
-from repro.serving.batcher import MicroBatcher
+from repro.serving.batcher import MicroBatcher, scan_meters
 from repro.serving.server import ServerConfigError
 from repro.serving.recsys_engine import (
     RecSysEngine,
@@ -74,7 +74,7 @@ class _InFlight(NamedTuple):
     parts: tuple  # ((chunk, bucket), ...) — chunk = [(ticket, query), ...]
     items: object  # (sum(buckets), top_k) device future
     scores: object  # (sum(buckets), top_k) device future
-    blocks: object = None  # (sum(buckets),) blocks-touched future | None
+    meters: dict | None = None  # the scan's counter futures (`scan_meters`)
     t_bucket: float = 0.0  # host time the buckets were taken off the queue
     t_dispatch: float = 0.0  # host time the staged pipeline was dispatched
 
@@ -185,7 +185,7 @@ class AsyncServer(MicroBatcher):
             self.n_padded += bucket - len(chunk)
             self.n_batches += 1
         return _InFlight(parts=tuple(parts), items=items, scores=top.scores,
-                         blocks=getattr(nns, "blocks_touched", None),
+                         meters=scan_meters(nns),
                          t_bucket=t_bucket,
                          t_dispatch=(time.perf_counter() if self.trace
                                      else 0.0))
@@ -207,10 +207,7 @@ class AsyncServer(MicroBatcher):
         t_sync = self.phases.enter("fanout")
         tail = ()
         if self.trace:
-            if inf.blocks is not None:
-                bt = np.asarray(inf.blocks)
-                self.registry.count("nns.blocks_touched", int(bt.sum()))
-                self.registry.count("nns.block_scan_queries", int(bt.size))
+            self._meter_scan(inf.meters)
             tail = (("bucket", inf.t_bucket),
                     ("dispatch", inf.t_dispatch),
                     ("scan", t_sync), ("rank", t_sync))

@@ -50,6 +50,7 @@ from typing import Sequence
 import jax
 import numpy as np
 
+from repro.core.nns import NNS_COUNTERS
 from repro.obs import MetricsRegistry, TicketTrace, phase_spans
 from repro.serving.hot_cache import CacheStats
 from repro.serving.recsys_engine import RecSysEngine, n_summary_blocks, \
@@ -65,6 +66,17 @@ from repro.serving.server import (
 # tickets traced beyond this are dropped (counted in `serving.trace_dropped`)
 # rather than growing the trace list without bound between take_trace calls
 TRACE_CAP = 100_000
+
+
+def scan_meters(nns) -> dict:
+    """The counters a scan's `NNSResult` holds (device futures, by field):
+    all a bucket keeps of its scan until `_meter_scan` writes them."""
+    meters = {}
+    for name in NNS_COUNTERS:
+        value = getattr(nns, name, None)
+        if value is not None:
+            meters[name] = value
+    return meters
 
 
 def default_buckets(max_batch: int) -> tuple[int, ...]:
@@ -248,7 +260,7 @@ class MicroBatcher:
                 scores = np.asarray(top.scores)
                 t_rank = phases.enter("fanout")
                 if self.trace:
-                    self._meter_scan(nns)
+                    self._meter_scan(scan_meters(nns))
                     tail = (("bucket", t_bucket), ("dispatch", t_dispatch),
                             ("scan", t_scan), ("rank", t_rank))
                 self._observe(chunk, items)
@@ -313,16 +325,23 @@ class MicroBatcher:
         out, self._trace = self._trace, []
         return out
 
-    def _meter_scan(self, nns) -> None:
-        """Accumulate pruned-scan effectiveness counters (blocks touched
-        per query vs the catalog's summary blocks -> scan_frac). Called
-        after the ranked items are materialized, so reading the tiny
-        per-query counts never stalls the pipeline."""
-        bt = getattr(nns, "blocks_touched", None)
+    def _meter_scan(self, meters: dict) -> None:
+        """Accumulate the scan's counters from `scan_meters(nns)`: pruned-
+        scan effectiveness (blocks touched per query vs the catalog's
+        summary blocks -> scan_frac) and the streaming kernel's cells and
+        merge rounds (`nns.scan_cells`, `nns.merge_cells`,
+        `nns.merge_rounds`). Called after the ranked items are
+        materialized, so reading the tiny counters never stalls the
+        pipeline."""
+        if not meters:
+            return
+        host = jax.device_get(meters)
+        bt = host.pop("blocks_touched", None)
         if bt is not None:
-            bt = np.asarray(bt)
             self.registry.count("nns.blocks_touched", int(bt.sum()))
             self.registry.count("nns.block_scan_queries", int(bt.size))
+        for name, value in host.items():
+            self.registry.count(f"nns.{name}", int(value))
 
     def _stack_np(self, queries: list[dict], bucket: int) -> dict:
         """Stack per-user queries into one padded (bucket, ...) host batch.

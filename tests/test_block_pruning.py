@@ -357,6 +357,43 @@ def test_engine_prune_knob_serves_identically():
             np.testing.assert_array_equal(scores, base[1])
 
 
+@pytest.mark.parametrize("mode", ["sync", "pipelined", "concurrent"])
+def test_kernel_counters_reach_the_registry(monkeypatch, mode):
+    """A pruned streaming-plan serving run through the Pallas kernel body
+    (interpret mode) writes the kernel's cells and merge rounds as the
+    `nns.scan_cells` / `nns.merge_cells` / `nns.merge_rounds` counters,
+    beside `nns.blocks_touched`, on every front end."""
+    from repro.data import synthetic
+    from repro.models import recsys as rs
+    from repro.serving import RecSysEngine, make_server
+
+    monkeypatch.setenv("REPRO_PALLAS_STREAMING_NNS", "interpret")
+    data = synthetic.make_movielens(n_users=30, n_items=300, history_len=5)
+    cfg = rs.YoutubeDNNConfig(
+        n_items=data.n_items,
+        user_features={"user_id": data.n_users, "gender": 3, "age": 7,
+                       "occupation": 21, "zip_bucket": 250},
+        history_len=5)
+    # the backend is chosen at trace time: drop programs traced with ref
+    jax.clear_caches()
+    engine = RecSysEngine.build(rs.init_youtubednn(jax.random.key(3), cfg),
+                                cfg, radius=109, n_candidates=13, top_k=4,
+                                hot_rows=16)
+    engine = dataclasses.replace(engine, scan_block=128)
+    server = make_server(engine, mode, max_batch=8)
+    try:
+        server.serve_many(synthetic.serving_queries(data, range(20)))
+    finally:
+        server.close()
+    snap = server.snapshot()
+    cells, merges, rounds = (snap.get(f"nns.{name}", 0) for name in
+                             ("scan_cells", "merge_cells", "merge_rounds"))
+    # buckets of 8, 8 and 4 queries, one kernel tile each, 3 blocks a scan
+    assert snap["nns.block_scan_queries"] == 20
+    assert 0 < merges <= cells <= 3 * 3
+    assert merges <= rounds <= 13 * merges
+
+
 def test_summary_is_pytree_with_static_block_rows():
     rng = np.random.default_rng(15)
     summary = build_block_summary(_uniform(rng, 2 * BR), BR)

@@ -98,11 +98,12 @@ def test_streaming_past_packed_key_capacity():
     idx, dist, cnt = jax.eval_shape(
         lambda qq, d: ref.streaming_nns_ref(qq, d, 10, 4), q, wide)
     assert idx.shape == (2, 4) and cnt.shape == (2,)
-    idx, dist, cnt = jax.eval_shape(
+    idx, dist, cnt, stats = jax.eval_shape(
         lambda qq, d: streaming_nns_pallas(
             qq, d, jnp.int32(d.shape[0]), radius=10, max_candidates=4),
         q, wide)
     assert idx.shape == (2, 4) and idx.dtype == jnp.int32
+    assert stats.shape == (3,) and stats.dtype == jnp.int32
 
 
 @pytest.mark.parametrize("superblock,block_n", [(256, 128), (512, 256),
@@ -137,6 +138,102 @@ def test_streaming_nns_kernel_n_valid_across_superblocks(key):
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
     assert (np.asarray(got[0]) < 389).all()
+
+
+def _at_distances(query, dists, rng):
+    """Rows at exactly Hamming distance `dists[i]` from `query` (words,)."""
+    words = query.shape[0]
+    rank = rng.random((len(dists), 32 * words)).argsort(1).argsort(1)
+    flips = (rank < np.asarray(dists)[:, None]).reshape(len(dists), words, 32)
+    packed = (flips.astype(np.uint64) << np.arange(32, dtype=np.uint64))
+    return query[None, :] ^ packed.sum(-1).astype(np.uint32)
+
+
+def _edge_dists(k, entering):
+    """Block 0 fills a K = `k` buffer at distance 50, block 1 brings
+    `entering` keys below it, block 2 one more, block 3 none (128 rows a
+    block, 200 = no match)."""
+    blocks = [[50] * k, [10] * entering, [5], []]
+    return np.concatenate([b + [200] * (128 - len(b)) for b in blocks])
+
+
+# name: (row distances from the base query, K, block_n, superblock, extra,
+#        expected stats for 8 identical queries or None)
+_MERGE_CASES = {
+    # the gate shuts after the first block: no later key is below the K-th
+    "ascending": (lambda r: np.sort(r.integers(0, 100, 1024)), 50, 128,
+                  None, False, (8, 1, 50)),
+    # every block brings 128 entering keys > K: K insertion rounds a block
+    "descending": (lambda r: np.sort(r.integers(0, 100, 1024))[::-1], 50,
+                   128, None, False, (8, 8, 400)),
+    # later rows at the K-th distance never enter
+    "ties_at_kth": (lambda r: np.r_[[20] * 40, [30] * 984], 50, 128, None,
+                    False, (8, 1, 50)),
+    "superblocks": (lambda r: r.integers(0, 130, 1000), 12, 128, 256, False,
+                    None),
+    "masked_pruned_n_valid": (
+        lambda r: np.where(np.arange(2048) // 256 % 3 == 1, 180,
+                           r.integers(0, 120, 2048)), 50, 128, None, True,
+        None),
+    "k1": (lambda r: r.integers(0, 130, 1500), 1, 256, None, False, None),
+    "k50": (lambda r: r.integers(0, 130, 1500), 50, 256, None, False, None),
+    # K fills the 128 buffer lanes: no slot past K to push keys into
+    "k128": (lambda r: r.integers(0, 130, 1500), 128, 256, None, False,
+             None),
+    # entering counts at the round cap's edges: m = 1 and m = K take m
+    # rounds, m = K + 1 takes K (its largest key cannot reach the top K)
+    "edge_at_k": (lambda r: _edge_dists(8, 8), 8, 128, None, False,
+                  (4, 3, 17)),
+    "edge_past_k": (lambda r: _edge_dists(8, 9), 8, 128, None, False,
+                    (4, 3, 17)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MERGE_CASES))
+@pytest.mark.parametrize("perturbed", [False, True],
+                         ids=["same_queries", "perturbed_queries"])
+def test_streaming_nns_merge_vs_ref(case, perturbed):
+    """The gated insertion merge bit-matches the oracle on row orders that
+    drive each of its paths; with 8 identical queries (one full tile)
+    its cells / merges / rounds counters are exact."""
+    dist_fn, k, block_n, superblock, extra, want_stats = _MERGE_CASES[case]
+    rng = np.random.default_rng(sorted(_MERGE_CASES).index(case))
+    base = rng.integers(0, 2**32, 8, dtype=np.uint32)
+    queries = np.repeat(base[None], 8, axis=0)
+    if perturbed:  # rows see slightly different distances
+        queries[4:] = _at_distances(base, [3, 5, 1, 7], rng)
+    dists = dist_fn(rng)
+    db = _at_distances(base, dists, rng)
+    n = db.shape[0]
+    radius, n_valid, mask, prune, prune_rows = 100, n, None, None, None
+    if extra:  # tombstones, a short prefix, and a sound prune mask
+        n_valid = n - 77
+        mask = rng.random(n) < 0.8
+        d = np.asarray(ref.hamming_distance_ref(queries, db))
+        hit = (d <= radius) & mask & (np.arange(n) < n_valid)
+        prune_rows = 2 * block_n
+        prune = ~hit.reshape(8, -1, prune_rows).any(-1)
+        assert prune.all(0).any() and not prune.all()
+        mask = jnp.asarray(mask)
+    queries, db = jnp.asarray(queries), jnp.asarray(db)
+    want = ref.streaming_nns_ref(
+        queries, db, radius, k, scan_block=block_n, n_valid=n_valid,
+        superblock=superblock, db_mask=mask, prune_blocks=prune,
+        prune_block_rows=prune_rows)
+    got = streaming_nns_pallas(
+        queries, db, jnp.int32(n_valid), mask, radius=radius,
+        max_candidates=k, block_n=block_n, superblock=superblock,
+        prune_blocks=None if prune is None else jnp.asarray(prune),
+        prune_block_rows=prune_rows, interpret=True)
+    for g, w, name in zip(got, want, ("indices", "distances", "counts")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
+    cells, merges, rounds = (int(v) for v in np.asarray(got[3]))
+    assert merges <= cells and merges <= rounds <= k * merges
+    if want_stats is not None and not perturbed:
+        assert (cells, merges, rounds) == want_stats
+    if prune is not None:  # pruned cells are not scanned
+        assert cells < n // block_n
 
 
 # ---------------------------------------------------------------------------

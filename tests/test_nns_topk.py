@@ -123,8 +123,7 @@ def test_streaming_accepts_arbitrary_db_mask(key):
                             db_mask=mask, scan_block=0)
     got = fixed_radius_nns(sigs[:3], sigs, radius=30, max_candidates=4,
                            db_mask=mask, scan_block=16)
-    for a, b in zip(want, got):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _assert_same_result(want, got)
 
 
 def test_sharded_matches_unsharded(key):

@@ -13,6 +13,7 @@ only the worker that runs this file loads the TPU library. Code that asks
 Pallas backend with `REPRO_PALLAS=pallas` for its own compile.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,7 +94,12 @@ def test_streaming_nns_compiles_two_superblocks(one_chip, pallas, masked,
             prune_block_rows=SUMMARY_ROWS if pruned else None)
 
     compiled = _compile(scan, *specs)
-    assert "%streaming_nns." in compiled.as_text()  # the kernel's name=
+    text = compiled.as_text()
+    assert "%streaming_nns." in text  # the kernel's name=
+    # the kernel's outputs hold the stats tile of each 8-query tile
+    tile = rf"s32\[{QUERIES // 8},8,128\]"
+    assert re.search(rf"%streaming_nns\.\d+ = \(.*{tile}.*custom-call",
+                     text)
     mem = compiled.memory_analysis()
     # the DB is read in place: no relayout copy of the 256 MiB table
     assert mem.temp_size_in_bytes < CATALOG * WORDS * 4 // 8
