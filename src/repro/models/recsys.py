@@ -1,5 +1,7 @@
 """The paper's two RecSys instances: YoutubeDNN (filtering + ranking) and
-Facebook DLRM (ranking). Sec. II-A / Fig. 1.
+Facebook DLRM (ranking). Sec. II-A / Fig. 1. DLRM's config also states
+DLRM-DCNv2, the MLPerf recommendation model: multi-hot bags per table and
+a low-rank cross network in place of the pairwise dots.
 
 Training uses dense fp32 embedding tables; serving quantizes every table to
 int8 (core.quantization) and runs lookups/pooling through the fused kernel
@@ -133,37 +135,98 @@ def ranking_loss(p, cfg: YoutubeDNNConfig, batch: dict) -> jax.Array:
 # DLRM (ranking on Criteo)
 # ---------------------------------------------------------------------------
 class DLRMConfig(NamedTuple):
+    """DLRM's shape. The defaults are the paper's (Table I): 26 one-hot
+    tables of 28,000 rows at dim 32 and the pairwise-dot interaction.
+    DLRM-DCNv2 (`bench/configs/dlrm-dcnv2-criteo1tb.json`) sets rows and
+    bag sizes per table and ``interaction="cross"``: `cross_layers`
+    low-rank layers of rank `cross_rank` over the concatenation of the
+    bottom MLP's output and the pooled bags (arXiv 2008.13535)."""
+
     n_dense: int = 13
     n_sparse: int = 26
-    cardinality: int = 28000  # rows per ET (Table I)
+    cardinality: int = 28000  # rows of every ET (Table I), unless table_rows
     embed_dim: int = EMBED_DIM
     bottom_dims: tuple = (256, 128, 32)  # paper Table I
     top_dims: tuple = (256, 64, 1)
+    table_rows: tuple | None = None  # rows of each ET
+    bag_sizes: tuple | None = None  # ids sum-pooled from each ET (1: one-hot)
+    interaction: str = "dot"  # "dot" (pairwise dots) | "cross" (DCNv2)
+    cross_layers: int = 3
+    cross_rank: int = 512
+
+    @property
+    def rows(self) -> tuple:
+        return self.table_rows or (self.cardinality,) * self.n_sparse
+
+    @property
+    def bags(self) -> tuple:
+        return self.bag_sizes or (1,) * self.n_sparse
+
+
+def init_dlrm_dense(key, cfg: DLRMConfig) -> dict:
+    """The bottom MLP, the interaction's weights and the top MLP, fan-in
+    scaled (`_mlp_init`); a cross layer's V maps the concatenation to the
+    rank, W back, with a zero bias."""
+    bottom = _mlp_init(fold_key(key, "bottom"),
+                       (cfg.n_dense,) + cfg.bottom_dims)
+    if cfg.interaction == "cross":
+        width = (cfg.n_sparse + 1) * cfg.embed_dim
+        if cfg.bottom_dims[-1] != cfg.embed_dim:
+            raise ValueError("a cross interaction needs the bottom MLP to "
+                             "end at embed_dim")
+        cross = []
+        for i in range(cfg.cross_layers):
+            v, w = _mlp_init(fold_key(key, "cross", str(i)),
+                             (width, cfg.cross_rank, width))
+            cross.append({"V": v["w"], "W": w["w"], "b": w["b"]})
+        top_in = width
+    else:
+        n_vec = cfg.n_sparse + 1
+        cross = []
+        top_in = n_vec * (n_vec - 1) // 2 + cfg.bottom_dims[-1]
+    return {"bottom": bottom, "cross": cross,
+            "top": _mlp_init(fold_key(key, "top"), (top_in,) + cfg.top_dims)}
 
 
 def init_dlrm(key, cfg: DLRMConfig) -> dict:
     tables = {}
-    for i in range(cfg.n_sparse):
+    for i, rows in enumerate(cfg.rows):
         tables[f"cat_{i:02d}"] = 0.05 * jax.random.normal(
-            fold_key(key, "dlrm", str(i)), (cfg.cardinality, cfg.embed_dim))
-    n_vec = cfg.n_sparse + 1
-    n_inter = n_vec * (n_vec - 1) // 2
-    return {
-        "tables": tables,
-        "bottom": _mlp_init(fold_key(key, "bottom"),
-                            (cfg.n_dense,) + cfg.bottom_dims),
-        "top": _mlp_init(fold_key(key, "top"),
-                         (n_inter + cfg.bottom_dims[-1],) + cfg.top_dims),
-    }
+            fold_key(key, "dlrm", str(i)), (rows, cfg.embed_dim))
+    return {"tables": tables, **init_dlrm_dense(key, cfg)}
+
+
+def pool_bags(tables: dict, cfg: DLRMConfig, sparse: jax.Array) -> list:
+    """Sum-pool each table's bag: `sparse` (..., sum(bags)) holds table i's
+    ids at its slice, in table order -> one (..., d) array a table."""
+    out, lo = [], 0
+    for i, n in enumerate(cfg.bags):
+        rows = tables[f"cat_{i:02d}"][sparse[..., lo:lo + n]]  # (..., n, d)
+        out.append(rows.sum(-2))
+        lo += n
+    return out
+
+
+def cross_net(layers: list, x0: jax.Array) -> jax.Array:
+    """DCNv2's low-rank cross network: x_{l+1} = x0 * (W_l (V_l x_l) + b_l)
+    + x_l, over the last axis."""
+    x = x0
+    for p in layers:
+        x = x0 * ((x @ p["V"]) @ p["W"] + p["b"]) + x
+    return x
 
 
 def dlrm_forward(p, cfg: DLRMConfig, batch: dict) -> jax.Array:
-    """batch: dense (B, 13), sparse (B, 26) int32 -> CTR logits (B,)."""
-    dense = _mlp_apply(p["bottom"], batch["dense"], final_act=True)  # (B, 32)
-    sparse = batch["sparse"]
-    embs = [p["tables"][f"cat_{i:02d}"][sparse[:, i]]
-            for i in range(cfg.n_sparse)]
-    vecs = jnp.stack([dense] + embs, axis=1)  # (B, 27, 32)
+    """batch: dense (..., 13) float, sparse (..., sum(bags)) int32 ids ->
+    CTR logits (...). Plain jax.numpy: per-table sum-pooled bags, then the
+    configured interaction and the top MLP. The dot interaction takes one
+    leading batch axis."""
+    dense = _mlp_apply(p["bottom"], batch["dense"], final_act=True)
+    embs = pool_bags(p["tables"], cfg, batch["sparse"])
+    if cfg.interaction == "cross":
+        x = cross_net(p["cross"], jnp.concatenate([dense] + embs, axis=-1))
+        return _mlp_apply(p["top"], x)[..., 0]
+    vecs = jnp.stack([dense] + embs, axis=1)  # (B, 27, d)
     inter = jnp.einsum("bid,bjd->bij", vecs, vecs)  # pairwise dots
     iu, ju = jnp.triu_indices(vecs.shape[1], k=1)
     flat = inter[:, iu, ju]  # (B, 351)

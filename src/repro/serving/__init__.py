@@ -18,7 +18,9 @@ rebuild of the current parameters).
 Every front-end implements the one `Server` protocol (submit -> ticket,
 result(ticket), flush, close, stats) and is constructed through
 `make_server(engine, mode="sync" | "pipelined" | "concurrent", **knobs)`
-— see serving/server.py and docs/SERVING.md."""
+— see serving/server.py and docs/SERVING.md. The engine is any
+`ServingEngine`: the YoutubeDNN `RecSysEngine` or the ranking-only
+`DLRMEngine` (DLRM-DCNv2's multi-hot int8 lookups and cross network)."""
 from repro.serving.async_server import AsyncServer
 from repro.serving.batcher import MicroBatcher, ServedQuery, default_buckets
 from repro.serving.frontend import ConcurrentFrontend, TicketTrace
@@ -27,14 +29,22 @@ from repro.serving.server import (
     STATUS_ERROR,
     STATUS_OK,
     STATUS_SHED,
+    Dispatched,
+    Field,
     QueueFullError,
     SchemaMismatchError,
     Server,
     ServerClosedError,
     ServerConfigError,
+    ServingEngine,
     ServingError,
     make_server,
     stats_view,
+)
+from repro.serving.dlrm_engine import (
+    DLRMEngine,
+    dlrm_lookup_step,
+    dlrm_rank_step,
 )
 from repro.serving.catalog import (
     DeltaFullError,
@@ -91,8 +101,11 @@ __all__ = [
     "BaseShardWriter",
     "CacheStats",
     "ConcurrentFrontend",
+    "DLRMEngine",
     "DeltaFullError",
     "DeltaShard",
+    "Dispatched",
+    "Field",
     "HotRowCache",
     "LiveCatalog",
     "LoadGen",
@@ -109,6 +122,7 @@ __all__ = [
     "ShadowRecord",
     "ServerClosedError",
     "ServerConfigError",
+    "ServingEngine",
     "ServingError",
     "TicketTrace",
     "TieredCatalog",
@@ -117,6 +131,8 @@ __all__ = [
     "cached_lookup",
     "compact_engine",
     "default_buckets",
+    "dlrm_lookup_step",
+    "dlrm_rank_step",
     "empty_delta",
     "engine_apply_updates",
     "engine_refresh_model",

@@ -1,4 +1,4 @@
-"""Pipelined (double-buffered) serving over the staged iMARS pipeline.
+"""Pipelined (double-buffered) serving over an engine's staged pipeline.
 
 iMARS's end-to-end win comes from keeping the filtering and ranking stages
 busy *simultaneously* (paper Fig. 3: the CMA banks scan while the crossbars
@@ -11,10 +11,12 @@ hides in lookup/compute *serialization*, not in any single kernel.
 
 `AsyncServer` recovers the overlap with JAX async dispatch — no threads:
 
-  * each bucket is dispatched through the **staged** serve pipeline
-    (`lookup_step` -> `scan_step` -> `rank_stage_step`, the fused
-    `serve_step` split at its stage boundaries) and the resulting device
-    futures are pushed onto a small ring of in-flight buckets;
+  * each bucket is dispatched through the engine's **staged** serve
+    pipeline (`ServingEngine.dispatch`: for a `RecSysEngine`
+    `lookup_step` -> `scan_step` -> `rank_stage_step`, the fused
+    `serve_step` split at its stage boundaries; for a `DLRMEngine` its
+    lookup and rank stages) and the resulting device futures are pushed
+    onto a small ring of in-flight buckets;
   * nothing blocks until the ring holds `depth` buckets: while bucket i's
     NNS scan runs on the device, the host is already stacking/padding
     bucket i+1 and dispatching its lookup stage — double-buffering for
@@ -31,8 +33,8 @@ buckets are concatenated into one routed super-batch per dispatch: the
 query-parallel `shard_map` splits its rows contiguously over the query
 axis, so concurrent buckets land on **disjoint query blocks** and scan the
 catalog in parallel instead of queueing behind each other. `coalesce`
-defaults to the query-axis size (1 — no coalescing — for unrouted
-engines) and can be forced for testing.
+defaults to the engine's `coalesce`, the query-axis size (1 — no
+coalescing — for unrouted engines), and can be forced for testing.
 
 Bit-for-bit contract (tested in tests/test_async_serving.py): pipelined
 serving returns exactly the items, scores, and cache counters the
@@ -58,10 +60,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import MetricsRegistry
-from repro.serving.batcher import MicroBatcher, scan_meters
-from repro.serving.server import ServerConfigError
-from repro.serving.recsys_engine import (
-    RecSysEngine,
+from repro.serving.batcher import MicroBatcher
+from repro.serving.server import ServerConfigError, ServingEngine
+# the stage programs a RecSysEngine's `dispatch` runs; kept importable
+# here, where tests/bench/test_bench_cells.py patches them
+from repro.serving.recsys_engine import (  # noqa: F401
     lookup_step,
     rank_stage_step,
     scan_step,
@@ -74,13 +77,13 @@ class _InFlight(NamedTuple):
     parts: tuple  # ((chunk, bucket), ...) — chunk = [(ticket, query), ...]
     items: object  # (sum(buckets), top_k) device future
     scores: object  # (sum(buckets), top_k) device future
-    meters: dict | None = None  # the scan's counter futures (`scan_meters`)
+    meters: dict | None = None  # the batch's counter futures
     t_bucket: float = 0.0  # host time the buckets were taken off the queue
     t_dispatch: float = 0.0  # host time the staged pipeline was dispatched
 
 
 class AsyncServer(MicroBatcher):
-    """Pipelined micro-batching server over a `RecSysEngine`.
+    """Pipelined micro-batching server over a `ServingEngine`.
 
     Drop-in for `MicroBatcher` (same submit/result/serve_many API, same
     bucketing, same counters) with a ring of up to `depth` in-flight
@@ -92,9 +95,10 @@ class AsyncServer(MicroBatcher):
       depth: in-flight ring size; 1 degenerates to synchronous serving,
         2 (default) double-buffers host work against device compute.
       coalesce: number of full buckets fused into one routed super-batch
-        per dispatch. Default: the engine's query-mesh axis size when
-        sharded with `query_axis=...`, else 1. Values > 1 route concurrent
-        buckets onto disjoint query blocks of the mesh.
+        per dispatch. Default: the engine's `coalesce` (a RecSysEngine's
+        query-mesh axis size when sharded with `query_axis=...`, else 1).
+        Values > 1 route concurrent buckets onto disjoint query blocks of
+        the mesh.
 
     Invariant: results bit-match the synchronous `MicroBatcher` for any
     depth / coalesce / bucket mix (tested).
@@ -102,7 +106,7 @@ class AsyncServer(MicroBatcher):
 
     mode = "pipelined"
 
-    def __init__(self, engine: RecSysEngine, *, max_batch: int = 256,
+    def __init__(self, engine: ServingEngine, *, max_batch: int = 256,
                  buckets: Sequence[int] | None = None, depth: int = 2,
                  coalesce: int | None = None, trace: bool = True,
                  registry: MetricsRegistry | None = None,
@@ -113,10 +117,7 @@ class AsyncServer(MicroBatcher):
         if depth < 1:
             raise ServerConfigError(f"ring depth must be >= 1, got {depth}")
         if coalesce is None:
-            routed = (engine.nns_mesh is not None
-                      and engine.nns_query_axis is not None)
-            coalesce = (engine.nns_mesh.shape[engine.nns_query_axis]
-                        if routed else 1)
+            coalesce = engine.coalesce
         if coalesce < 1:
             raise ServerConfigError(f"coalesce must be >= 1, got {coalesce}")
         self.depth = depth
@@ -175,17 +176,14 @@ class AsyncServer(MicroBatcher):
         batch = {k: jnp.asarray(v) for k, v in host.items()}
         self.phases.enter("dispatch")
         with self._stats_lock:
-            u, pooled, self._stats = lookup_step(self.engine, batch,
-                                                 self._stats)
-            nns = scan_step(self.engine, u)
-            items, top, self._stats = rank_stage_step(
-                self.engine, batch, nns.indices, u, pooled, self._stats)
+            out = self.engine.dispatch(batch, self._stats)
+            self._stats = out.acc
         for chunk, bucket in parts:
             self.n_served += len(chunk)
             self.n_padded += bucket - len(chunk)
             self.n_batches += 1
-        return _InFlight(parts=tuple(parts), items=items, scores=top.scores,
-                         meters=scan_meters(nns),
+        return _InFlight(parts=tuple(parts), items=out.items,
+                         scores=out.scores, meters=out.meters,
                          t_bucket=t_bucket,
                          t_dispatch=(time.perf_counter() if self.trace
                                      else 0.0))
@@ -207,7 +205,7 @@ class AsyncServer(MicroBatcher):
         t_sync = self.phases.enter("fanout")
         tail = ()
         if self.trace:
-            self._meter_scan(inf.meters)
+            self._meter(inf.meters)
             tail = (("bucket", inf.t_bucket),
                     ("dispatch", inf.t_dispatch),
                     ("scan", t_sync), ("rank", t_sync))

@@ -1,10 +1,11 @@
-"""Micro-batching request queue in front of the jitted iMARS serve step.
+"""Micro-batching request queue in front of an engine's jitted serve steps.
 
 Paper mapping (Fig. 3): each submitted query is one user hitting the
 recommendation fabric. The batcher plays the role of the query scheduler in
 front of the pipeline — it accumulates queries, pads/buckets them to a small
 set of fixed batch shapes, and feeds each bucket through **one** jitted
-`serve_step` whose stages are exactly the paper's computation flow:
+`serve_step` (the engine's `dispatch(..., fused=True)`) whose stages are
+exactly the paper's computation flow:
 
     queue  ->  (1a/1b*) UIET/ItET lookups + pooling   (hot-row cache + int8
                         embedding_pool — CMA RAM mode, Sec. III-A1)
@@ -14,6 +15,11 @@ set of fixed batch shapes, and feeds each bucket through **one** jitted
                         a device mesh)
            ->  (2a-2d)  ranking DNN: CTR per candidate
            ->  (2e)     CTR-buffer threshold top-k -> final items
+
+The front ends see an engine only through the `ServingEngine` protocol
+(serving/server.py): its request fields, its programs behind
+`dispatch`, and its `top_k`. The stages above are `RecSysEngine`'s; a
+`DLRMEngine` runs lookup and rank only, through the same classes.
 
 Bucketing keeps the set of compiled shapes tiny (powers of two up to
 `max_batch`): a bucket compiles once and is reused forever after, so the
@@ -25,9 +31,9 @@ this used to replicate the last pending query, which made the padded tail
 of a bucket — e.g. a queue smaller than the smallest bucket — re-serve real
 ids and lean on the `valid` mask alone to keep the counters honest).
 
-The hot-cache hit accumulator is donated to the jitted step (`serve_step`'s
-third argument), so the counters update in place across batches without a
-host round-trip per flush.
+The hot-cache hit accumulator is donated to the engine's jitted stages
+(`ServingEngine.dispatch`'s `acc`), so the counters update in place
+across batches without a host round-trip per flush.
 
 Telemetry (docs/OBSERVABILITY.md): with ``trace=True`` (the default)
 every ticket carries a stage-span chain (submit -> admit -> bucket ->
@@ -50,33 +56,20 @@ from typing import Sequence
 import jax
 import numpy as np
 
-from repro.core.nns import NNS_COUNTERS
 from repro.obs import MetricsRegistry, TicketTrace, phase_spans
 from repro.serving.hot_cache import CacheStats
-from repro.serving.recsys_engine import RecSysEngine, n_summary_blocks, \
-    serve_step
 from repro.serving.server import (
     STATUS_OK,
     SchemaMismatchError,
     ServerClosedError,
     ServerConfigError,
+    ServingEngine,
     stats_view,
 )
 
 # tickets traced beyond this are dropped (counted in `serving.trace_dropped`)
 # rather than growing the trace list without bound between take_trace calls
 TRACE_CAP = 100_000
-
-
-def scan_meters(nns) -> dict:
-    """The counters a scan's `NNSResult` holds (device futures, by field):
-    all a bucket keeps of its scan until `_meter_scan` writes them."""
-    meters = {}
-    for name in NNS_COUNTERS:
-        value = getattr(nns, name, None)
-        if value is not None:
-            meters[name] = value
-    return meters
 
 
 def default_buckets(max_batch: int) -> tuple[int, ...]:
@@ -112,17 +105,18 @@ class ServedQuery:
 
 
 class MicroBatcher:
-    """Synchronous micro-batching queue over a `RecSysEngine`.
+    """Synchronous micro-batching queue over a `ServingEngine`.
 
-    submit() enqueues single-user queries (dicts of scalars + the history
-    vector); flush() drains the queue through bucket-shaped jitted serve
-    steps; results() hands back per-ticket recommendations in submission
-    order. `serve_many` is the one-call convenience wrapper.
+    submit() enqueues single requests (dicts holding the engine's
+    `request_fields`); flush() drains the queue through bucket-shaped
+    jitted serve steps; results() hands back per-ticket recommendations
+    in submission order. `serve_many` is the one-call convenience
+    wrapper.
     """
 
     mode = "sync"
 
-    def __init__(self, engine: RecSysEngine, *, max_batch: int = 256,
+    def __init__(self, engine: ServingEngine, *, max_batch: int = 256,
                  buckets: Sequence[int] | None = None, trace: bool = True,
                  registry: MetricsRegistry | None = None,
                  record_tickets: bool = True):
@@ -133,7 +127,7 @@ class MicroBatcher:
             raise ServerConfigError(
                 f"largest bucket {self.buckets[-1]} must equal "
                 f"max_batch={max_batch} (buckets={self.buckets})")
-        self._feature_names = tuple(sorted(engine.cfg.user_features.keys()))
+        self._fields = engine.request_fields
         self._pending: list[tuple[int, dict]] = []
         self._results: dict[int, ServedQuery] = {}
         self._next_ticket = 0
@@ -146,8 +140,9 @@ class MicroBatcher:
         self._cache_read = {"hits": 0, "lookups": 0}
         # optional lookup-frequency hook (LiveCatalog.attach wires it to
         # LiveCatalog.observe): called per served chunk with one flat int
-        # array of the item ids the batch looked up — history rows and the
-        # served candidates. Pure host-side telemetry; never affects
+        # array of the item ids the batch looked up, as the engine's
+        # `looked_up_ids` gives them (a RecSysEngine: history rows and the
+        # served candidates). Pure host-side telemetry; never affects
         # serving results.
         self.observer = None
         self._tenant_of: dict[int, int] = {}  # ticket -> submitting tenant
@@ -170,7 +165,7 @@ class MicroBatcher:
         self.n_trace_dropped = 0
 
     # ------------------------------------------------------------------
-    def swap_engine(self, engine: RecSysEngine) -> None:
+    def swap_engine(self, engine: ServingEngine) -> None:
         """Atomically swap to a new engine epoch/update view.
 
         The live-catalog publication point (`catalog.LiveCatalog.attach`):
@@ -179,11 +174,12 @@ class MicroBatcher:
         device buffers of the old engine value and finish on that epoch —
         a bucket is always entirely one epoch, never mixed. The hot-cache
         hit accumulator and the served/padded counters carry over.
+        An engine whose `request_fields` differ raises
+        `SchemaMismatchError`.
         """
-        if tuple(sorted(engine.cfg.user_features.keys())) \
-                != self._feature_names:
+        if engine.request_fields != self._fields:
             raise SchemaMismatchError(
-                "swap_engine: user-feature schema changed; "
+                "swap_engine: request schema changed; "
                 "start a new server instead")
         self.engine = engine
 
@@ -233,10 +229,11 @@ class MicroBatcher:
         """Drain the queue through bucket-shaped jitted serve steps.
 
         With tracing on, the synchronous path observes the *real* device
-        stage boundaries: an intermediate block on the NNS result marks
-        the scan->rank edge (free here — this path blocks on the ranked
-        items immediately after anyway), and pruned scans feed their
-        blocks-touched counts into the registry. The flush's phases
+        stage boundaries: an intermediate block on the dispatch's `edge`
+        (the NNS result of a RecSysEngine) marks the scan->rank edge (free
+        here — this path blocks on the ranked items immediately after
+        anyway), and the batch's meters (a pruned scan's blocks-touched
+        counts, say) are summed into the registry. The flush's phases
         (stack, dispatch, sync, fanout) tile its wall time.
         """
         phases = self.phases
@@ -249,18 +246,19 @@ class MicroBatcher:
                 batch = self._stack([q for _, q in chunk], bucket)
                 phases.enter("dispatch")
                 with self._stats_lock:
-                    items, top, nns, self._stats = serve_step(
-                        self.engine, batch, self._stats)
+                    out = self.engine.dispatch(batch, self._stats,
+                                               fused=True)
+                    self._stats = out.acc
                 t_dispatch = phases.enter("sync")
                 tail = ()
                 if self.trace:
-                    jax.block_until_ready(nns.indices)
+                    jax.block_until_ready(out.edge)
                     t_scan = time.perf_counter()
-                items = np.asarray(items)
-                scores = np.asarray(top.scores)
+                items = np.asarray(out.items)
+                scores = np.asarray(out.scores)
                 t_rank = phases.enter("fanout")
                 if self.trace:
-                    self._meter_scan(scan_meters(nns))
+                    self._meter(out.meters)
                     tail = (("bucket", t_bucket), ("dispatch", t_dispatch),
                             ("scan", t_scan), ("rank", t_rank))
                 self._observe(chunk, items)
@@ -271,16 +269,14 @@ class MicroBatcher:
                 self.n_batches += 1
 
     def _observe(self, chunk, items) -> None:
-        """Feed the frequency observer one served chunk's item lookups:
-        the real (non-padding) queries' history ids plus the items served
-        back to them. Invalid (-1) ids are filtered by the observer."""
+        """Feed the frequency observer one served chunk's item lookups, as
+        the engine's `looked_up_ids` names them for the real (non-padding)
+        requests and the items served back to them. Invalid (-1) ids are
+        filtered by the observer."""
         if self.observer is None or not len(chunk):
             return
-        hist = np.concatenate(
-            [np.asarray(q["history"], np.int64).reshape(-1)
-             for _, q in chunk])
-        served = np.asarray(items[: len(chunk)], np.int64).reshape(-1)
-        self.observer(np.concatenate([hist, served]))
+        self.observer(self.engine.looked_up_ids(
+            [q for _, q in chunk], items[: len(chunk)]))
 
     def _resolve(self, ticket: int, items, scores, tail: tuple) -> None:
         """Record one served ticket (+ its tenant accounting + spans);
@@ -325,45 +321,39 @@ class MicroBatcher:
         out, self._trace = self._trace, []
         return out
 
-    def _meter_scan(self, meters: dict) -> None:
-        """Accumulate the scan's counters from `scan_meters(nns)`: pruned-
-        scan effectiveness (blocks touched per query vs the catalog's
-        summary blocks -> scan_frac) and the streaming kernel's cells and
-        merge rounds (`nns.scan_cells`, `nns.merge_cells`,
-        `nns.merge_rounds`). Called after the ranked items are
-        materialized, so reading the tiny counters never stalls the
-        pipeline."""
+    def _meter(self, meters: dict) -> None:
+        """Add a batch's meters (`Dispatched.meters`: counter name -> value,
+        an array summed) to the registry's counters: a RecSysEngine's
+        pruned-scan blocks touched and streaming-kernel cells and merge
+        rounds (`nns.*`), a DLRMEngine's samples and bag rows (`dlrm.*`).
+        Called after the batch's results are materialized, so reading the
+        tiny counters never stalls the pipeline."""
         if not meters:
             return
-        host = jax.device_get(meters)
-        bt = host.pop("blocks_touched", None)
-        if bt is not None:
-            self.registry.count("nns.blocks_touched", int(bt.sum()))
-            self.registry.count("nns.block_scan_queries", int(bt.size))
-        for name, value in host.items():
-            self.registry.count(f"nns.{name}", int(value))
+        for name, value in jax.device_get(meters).items():
+            self.registry.count(name, int(np.sum(value)))
 
     def _stack_np(self, queries: list[dict], bucket: int) -> dict:
-        """Stack per-user queries into one padded (bucket, ...) host batch.
+        """Stack requests into one padded (bucket, ...) host batch, a
+        column a `request_fields` entry, plus the ``valid`` row mask.
 
-        Padding rows are INVALID queries: every id is -1, so they read zero
-        rows and can never count as hot-cache lookups — even without the
-        `valid` row mask (which still marks real queries so their results
-        are the ones handed back). Returns numpy arrays so callers (the
-        pipelined `AsyncServer`) can concatenate several buckets into one
-        routed super-batch before the single device transfer.
+        Padding rows hold each field's `pad` (a RecSysEngine's ids are -1,
+        so its padding rows read zero rows and can never count as
+        hot-cache lookups — even without the `valid` row mask, which still
+        marks real queries so their results are the ones handed back).
+        Returns numpy arrays so callers (the pipelined `AsyncServer`) can
+        concatenate several buckets into one routed super-batch before the
+        single device transfer.
         """
         n = len(queries)
-        history_len = len(np.asarray(queries[0]["history"]))
-        batch = {
-            name: np.full(bucket, -1, np.int32) for name in
-            (*self._feature_names, "genre")
-        }
-        batch["history"] = np.full((bucket, history_len), -1, np.int32)
-        for name in (*self._feature_names, "genre"):
-            batch[name][:n] = [q[name] for q in queries]
-        batch["history"][:n] = np.stack(
-            [np.asarray(q["history"], np.int32) for q in queries])
+        batch = {}
+        for name, shape, dtype, pad in self._fields:
+            col = batch[name] = np.full((bucket, *shape), pad, dtype)
+            if shape:
+                col[:n] = np.stack([np.asarray(q[name], dtype)
+                                    for q in queries])
+            else:
+                col[:n] = [q[name] for q in queries]
         batch["valid"] = np.arange(bucket) < n
         return batch
 
@@ -409,7 +399,8 @@ class MicroBatcher:
         reg.gauge("serving.trace_dropped", self.n_trace_dropped)
         reg.gauge("cache.hits", cache["hits"])
         reg.gauge("cache.lookups", cache["lookups"])
-        reg.gauge("nns.summary_blocks", n_summary_blocks(self.engine))
+        for name, value in self.engine.gauges().items():
+            reg.gauge(name, value)
         reg.info("serving.per_tenant",
                  {t: dict(v) for t, v in self._per_tenant.items()})
 

@@ -61,7 +61,6 @@ import numpy as np
 from repro.obs import MetricsRegistry, TicketTrace, unwatch_gc, watch_gc
 from repro.serving.async_server import AsyncServer
 from repro.serving.batcher import TRACE_CAP, ServedQuery
-from repro.serving.recsys_engine import RecSysEngine
 from repro.serving.server import (
     STATUS_ERROR,
     STATUS_OK,
@@ -69,6 +68,7 @@ from repro.serving.server import (
     QueueFullError,
     ServerClosedError,
     ServerConfigError,
+    ServingEngine,
     ServingError,
     stats_view,
 )
@@ -80,7 +80,8 @@ class ConcurrentFrontend:
     construct via ``make_server(engine, mode="concurrent", ...)``.
 
     Args:
-      engine: the serving engine (local or sharded).
+      engine: the serving engine (a `ServingEngine`: a local or sharded
+        `RecSysEngine`, or a `DLRMEngine`).
       tenants: tenant count; tenant ids are ``0..tenants-1``.
       queue_depth: max waiting queries per tenant queue; a submit beyond
         it is shed (``None`` = unbounded, never sheds).
@@ -100,7 +101,7 @@ class ConcurrentFrontend:
 
     mode = "concurrent"
 
-    def __init__(self, engine: RecSysEngine, *, tenants: int = 1,
+    def __init__(self, engine: ServingEngine, *, tenants: int = 1,
                  queue_depth: int | None = 256, max_batch: int = 256,
                  buckets: Sequence[int] | None = None, depth: int = 2,
                  coalesce: int | None = None, drain_chunk: int | None = None,
@@ -386,7 +387,7 @@ class ConcurrentFrontend:
     def engine(self):
         return self._inner.engine
 
-    def swap_engine(self, engine: RecSysEngine) -> None:
+    def swap_engine(self, engine: ServingEngine) -> None:
         """Epoch swap between drain chunks (LiveCatalog publication point).
 
         Serializes against the drain thread: the swap lands between inner

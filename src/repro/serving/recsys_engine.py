@@ -43,6 +43,7 @@ from repro.core import cost_model as cm
 from repro.core.embedding import embedding_bag
 from repro.core.lsh import lsh_signature, make_lsh_projections
 from repro.core.nns import (
+    NNS_COUNTERS,
     NNSResult,
     build_block_summary,
     delta_scan,
@@ -65,6 +66,7 @@ from repro.serving.hot_cache import (
     build_hot_cache,
     cached_embedding_bag,
 )
+from repro.serving.server import Dispatched, Field
 from repro.utils import FrozenMapping, pytree_dataclass
 
 
@@ -290,6 +292,55 @@ class RecSysEngine:
         items, top, nns, stats = serve_step(self, batch, CacheStats.zero())
         return ServeResult(items=items, topk=top, nns=nns,
                            cost=self.query_cost(), stats=stats)
+
+    # ------------------------------------------------------------------
+    # the front ends' engine protocol (serving/server.py `ServingEngine`)
+    # ------------------------------------------------------------------
+    @property
+    def request_fields(self) -> tuple[Field, ...]:
+        """One int32 id a user feature (sorted) and the genre, then the
+        (history_len,) history; padding rows hold -1 (they read zero
+        rows and count no cache lookups)."""
+        scalars = (*sorted(self.cfg.user_features), "genre")
+        return (*(Field(name, (), np.int32, -1) for name in scalars),
+                Field("history", (self.cfg.history_len,), np.int32, -1))
+
+    @property
+    def coalesce(self) -> int:
+        """Buckets a dispatch carries: the query-mesh axis size of an
+        engine sharded with `query_axis=...`, else 1."""
+        if self.nns_mesh is None or self.nns_query_axis is None:
+            return 1
+        return self.nns_mesh.shape[self.nns_query_axis]
+
+    def dispatch(self, batch: dict, acc: CacheStats, *,
+                 fused: bool = False) -> Dispatched:
+        """Dispatch one padded batch: `lookup_step` -> `scan_step` ->
+        `rank_stage_step`, or with `fused` the one program `serve_step`
+        (bit-identical). The meters are the scan's counters (`nns.*`);
+        the edge is the candidates."""
+        if fused:
+            items, top, nns, acc = serve_step(self, batch, acc)
+        else:
+            u, pooled, acc = lookup_step(self, batch, acc)
+            nns = scan_step(self, u)
+            items, top, acc = rank_stage_step(self, batch, nns.indices, u,
+                                              pooled, acc)
+        return Dispatched(items=items, scores=top.scores,
+                          meters=scan_meters(nns), acc=acc,
+                          edge=nns.indices)
+
+    def gauges(self) -> dict:
+        return {"nns.summary_blocks": n_summary_blocks(self)}
+
+    @staticmethod
+    def looked_up_ids(queries: list[dict], items) -> np.ndarray:
+        """The item ids served requests looked up: their history rows
+        and the items served back to them (the `observer` hook's feed)."""
+        hist = [np.asarray(q["history"], np.int64).reshape(-1)
+                for q in queries]
+        return np.concatenate(
+            hist + [np.asarray(items, np.int64).reshape(-1)])
 
     # ------------------------------------------------------------------
     # hardware cost accounting (per query)
@@ -526,6 +577,22 @@ serve_step = jax.jit(_serve_step, donate_argnums=(2,))
 lookup_step = jax.jit(_lookup_stage, donate_argnums=(2,))
 scan_step = jax.jit(_scan_stage)
 rank_stage_step = jax.jit(_rank_stage, donate_argnums=(5,))
+
+
+def scan_meters(nns: NNSResult) -> dict:
+    """The counters a scan's result holds, as registry counters: the
+    pruned scan's blocks touched and the queries they are over, and the
+    streaming kernel's cells and merge rounds (`core.nns.NNS_COUNTERS`);
+    device futures, read once the batch retires."""
+    meters = {}
+    for name in NNS_COUNTERS:
+        value = getattr(nns, name, None)
+        if value is None:
+            continue
+        meters[f"nns.{name}"] = value
+        if name == "blocks_touched":
+            meters["nns.block_scan_queries"] = int(np.prod(value.shape))
+    return meters
 
 
 def n_summary_blocks(engine: RecSysEngine) -> int:

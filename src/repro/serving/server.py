@@ -26,10 +26,18 @@ Bit-for-bit contract (tested in tests/test_server_protocol.py): for the
 same engine and the same admitted query stream, every mode returns
 identical items, scores, and hot-cache counters — front-ends move *time*
 (batching, pipelining, threading), never results.
+
+The front ends know an engine only through `ServingEngine` below: the
+fields of a request (to stack, pad and check a swapped engine's schema),
+`dispatch`, which runs the engine's staged jitted programs in order on one
+padded batch, and the `top_k` of a result. `RecSysEngine` (YoutubeDNN:
+lookup, filtering scan, rank) and `DLRMEngine` (DLRM: lookup, rank) both
+implement it, so every mode serves either with no per-model code.
 """
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, Protocol, runtime_checkable
+from typing import TYPE_CHECKING, Any, NamedTuple, Protocol, \
+    runtime_checkable
 
 if TYPE_CHECKING:  # pragma: no cover - type-only import, avoids a cycle
     from repro.serving.batcher import ServedQuery
@@ -64,6 +72,64 @@ class ServerClosedError(ServingError):
 
 class QueueFullError(ServingError):
     """A bounded tenant queue is full and shedding is disabled."""
+
+
+class Field(NamedTuple):
+    """One field of a request: each request dict holds `name` as an array
+    of `shape` (() for a scalar); a batch stacks them to (bucket, *shape)
+    of `dtype`, and padding rows hold `pad`."""
+
+    name: str
+    shape: tuple
+    dtype: Any
+    pad: Any
+
+
+class Dispatched(NamedTuple):
+    """One padded batch dispatched through an engine's staged programs:
+    device futures, nothing waited on."""
+
+    items: Any  # (B, top_k) result ids, -1 padded
+    scores: Any  # (B, top_k) their scores
+    meters: dict  # counter name -> device (or host) value, summed into
+    # the registry once the batch retires (tracing on)
+    acc: Any  # the donated accumulator after this batch (`CacheStats`)
+    edge: Any  # a future ready once the stages before the last are done
+
+
+@runtime_checkable
+class ServingEngine(Protocol):
+    """What the front ends need of an engine.
+
+    * ``request_fields``: the request schema (`Field`s, in stacking order);
+      batches also get a (B,) bool ``valid`` mask of the real rows;
+    * ``dispatch(batch, acc, fused=False)``: run the staged jitted
+      programs in order on one padded batch and accumulator `acc`
+      (donated), and return their `Dispatched` futures without waiting;
+      with `fused`, one program for the whole pipeline where the engine
+      has one (the synchronous front end, which waits on every batch);
+    * ``top_k``: the width of a result;
+    * ``coalesce``: how many full buckets one dispatch may carry (the
+      query-mesh width of a routed engine, else 1);
+    * ``gauges()``: the engine's static gauges for `snapshot()`;
+    * ``looked_up_ids(queries, items)``: the ids a served chunk looked
+      up, for a lookup-frequency ``observer`` (a `LiveCatalog` attached).
+    """
+
+    top_k: int
+
+    @property
+    def request_fields(self) -> tuple: ...
+
+    @property
+    def coalesce(self) -> int: ...
+
+    def dispatch(self, batch: dict, acc, *,
+                 fused: bool = False) -> Dispatched: ...
+
+    def gauges(self) -> dict: ...
+
+    def looked_up_ids(self, queries: list, items) -> Any: ...
 
 
 @runtime_checkable
@@ -166,7 +232,8 @@ def make_server(engine, mode: str = "sync", **knobs) -> "Server":
     """Construct a serving front-end by mode — the one public entry point.
 
     Args:
-      engine: the `RecSysEngine` to serve from (local or sharded).
+      engine: the `ServingEngine` to serve from: a `RecSysEngine` (local
+        or sharded) or a `DLRMEngine`.
       mode: ``"sync"`` (MicroBatcher: one bucket at a time),
         ``"pipelined"`` (AsyncServer: depth-N ring of in-flight buckets),
         or ``"concurrent"`` (ConcurrentFrontend: per-tenant bounded queues

@@ -20,7 +20,10 @@ def _public_api():
     from repro.serving import (
         AsyncServer,
         ConcurrentFrontend,
+        DLRMEngine,
         DeltaShard,
+        Dispatched,
+        Field,
         LiveCatalog,
         LoadGen,
         MicroBatcher,
@@ -39,6 +42,10 @@ def _public_api():
         rank_step,
         recsys_engine,
         scan_step,
+        ServingEngine,
+        dlrm_engine,
+        dlrm_lookup_step,
+        dlrm_rank_step,
         serve_step,
         server,
         stats_view,
@@ -148,6 +155,19 @@ def _public_api():
         ("lookup_step", lookup_step),
         ("scan_step", scan_step),
         ("rank_stage_step", rank_stage_step),
+        # the front ends' engine protocol and the DLRM engine
+        ("ServingEngine", ServingEngine),
+        ("Field", Field),
+        ("Dispatched", Dispatched),
+        ("RecSysEngine.dispatch", RecSysEngine.dispatch),
+        ("RecSysEngine.request_fields", RecSysEngine.request_fields),
+        ("serving.dlrm_engine", dlrm_engine),
+        ("DLRMEngine", DLRMEngine),
+        ("DLRMEngine.from_row_blocks", DLRMEngine.from_row_blocks),
+        ("DLRMEngine.dispatch", DLRMEngine.dispatch),
+        ("dlrm_lookup_step", dlrm_lookup_step),
+        ("dlrm_rank_step", dlrm_rank_step),
+        ("embedding_bag", dlrm_engine.bag_sums),
         # NNS entries
         ("fixed_radius_nns", nns.fixed_radius_nns),
         ("BlockSummary", nns.BlockSummary),

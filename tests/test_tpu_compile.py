@@ -194,3 +194,48 @@ def test_sharded_catalog_steps_compile_on_four_chips(topo, pallas, axis):
     cand = _spec(rep, (b, K), jnp.int32)
     _compile(rse._scan_stage, specs, u)
     _compile(rse._rank_stage, specs, batch, cand, u, u, stats)
+
+
+DLRM_BAGS = (3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12,
+             100, 27, 10, 3, 1, 1)
+DLRM_ROWS = (10_000_000, 39060, 17295, 7424, 20265, 3, 7122, 1543, 63,
+             10_000_000, 3067956, 405282, 10, 2209, 11938, 155, 4, 976, 14,
+             10_000_000, 10_000_000, 10_000_000, 590152, 12973, 108, 36)
+
+
+@pytest.mark.parametrize("stage", ["lookup", "rank"])
+def test_dlrm_stages_compile(one_chip, stage):
+    """DLRM-DCNv2's staged programs at published widths, for one chip's
+    tables (54,184,588 int8 rows of 128) and the cell's bucket of 16
+    requests of 512 candidates, at highest precision: they fit the chip."""
+    from repro.core.quantization import QuantizedTensor
+    from repro.models import recsys as rs
+    from repro.serving import dlrm_engine as de
+
+    cfg = rs.DLRMConfig(n_dense=13, n_sparse=26, embed_dim=128,
+                        bottom_dims=(512, 256, 128),
+                        top_dims=(1024, 1024, 512, 256, 1),
+                        table_rows=DLRM_ROWS, bag_sizes=DLRM_BAGS,
+                        interaction="cross", cross_layers=3, cross_rank=512)
+    dense = jax.eval_shape(lambda: rs.init_dlrm_dense(jax.random.key(0),
+                                                      cfg))
+    as_spec = lambda x: _spec(one_chip, x.shape, x.dtype)  # noqa: E731
+    rows = sum(DLRM_ROWS)
+    engine = de.DLRMEngine(
+        table_q=QuantizedTensor(values=_spec(one_chip, (rows, 128), jnp.int8),
+                                scales=_spec(one_chip, (rows, 1),
+                                             jnp.float32)),
+        params=jax.tree.map(as_spec, dense), cfg=cfg)
+    b, c = 16, 512
+    batch = {"dense": _spec(one_chip, (b, c, 13), jnp.float32),
+             "sparse": _spec(one_chip, (b, c, sum(DLRM_BAGS)), jnp.int32),
+             "valid": _spec(one_chip, (b,), jnp.bool_)}
+    with jax.default_matmul_precision("highest"):
+        if stage == "lookup":
+            lowered = jax.jit(de._dlrm_lookup_stage).lower(engine, batch)
+        else:
+            pooled = _spec(one_chip, (b, c, 26, 128), jnp.float32)
+            lowered = jax.jit(de._dlrm_rank_stage).lower(engine, batch,
+                                                         pooled)
+        mem = lowered.compile().memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9 * 0.9
