@@ -8,18 +8,20 @@ answers with the `top_k` candidate positions by logit. It serves through
 `ServingEngine` protocol (serving/server.py), in two staged programs:
 
   * lookup (`dlrm_lookup_step`, program ``_dlrm_lookup_stage``): every
-    bag sum-pooled from its int8 table by XLA's gather and sum
-    (`bag_sums`): (B, C, sum(bags)) ids -> (B, C, tables, d) f32;
+    bag sum-pooled from its int8 table by XLA's gather, a slot at a time,
+    with the scaling and sums fused onto it (`bag_sums`):
+    (B, C, sum(bags)) ids -> (B, C, tables, d) f32;
   * rank (`dlrm_rank_step`, program ``_dlrm_rank_stage``): the bottom MLP,
     DCNv2's low-rank cross network, the top MLP, and the top-k of each
     request's logits.
 
 The tables are row-wise int8 with f32 scales, quantized at ingestion
-(`core.quantization.quantize_rowwise`), and stored back to back in one
-array: table i starts at row ``offsets[i]``. There is no hot-row cache
-and no live catalog on this path: on the device a cached row saves no
-bytes. Padding requests hold id 0 in every slot (a real row, read and
-discarded).
+(`core.quantization.quantize_rowwise`), and stored back to back: table i
+starts at row ``offsets[i]``. The scales are stored 128 to a row
+(`PackedRows`), so that a slot's rows and its scales are two row
+gathers. There is no hot-row cache and no live catalog on this path: on
+the device a cached row saves no bytes. Padding requests hold id 0 in
+every slot (a real row, read and discarded).
 """
 from __future__ import annotations
 
@@ -30,25 +32,43 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.quantization import QuantizedTensor, quantize_rowwise
+from repro.core.quantization import quantize_rowwise
 from repro.models import recsys as rs
 from repro.serving.hot_cache import CacheStats
 from repro.serving.server import Dispatched, Field
-from repro.utils import pytree_dataclass
+from repro.utils import cdiv, pytree_dataclass
+
+SCALE_LANES = 128  # scales a row of scales holds
+
+
+@pytree_dataclass
+class PackedRows:
+    """Row-wise int8 rows and their f32 scales, the scales packed for
+    XLA's row gather.
+
+    ``values`` (rows, d) int8, as `quantize_rowwise` makes them;
+    ``scales`` (rows / 128, 128) f32: row ``r``'s scale at
+    ``[r // 128, r % 128]``, where `quantize_rowwise` gives (rows, 1).
+    The same bytes, so ``nbytes`` are the same.
+    """
+
+    values: jax.Array
+    scales: jax.Array
 
 
 @pytree_dataclass(meta_fields=("cfg", "candidates", "top_k"))
 class DLRMEngine:
     """A DLRM-DCNv2 ranking engine as a jit-able pytree.
 
-    Array fields: the int8 tables (one `QuantizedTensor`, tables back to
-    back) and the dense weights; `cfg`, `candidates` and `top_k` are static.
-    Build with `from_row_blocks` (float rows streamed in fixed-size
-    blocks, for tables too large to hold in float), or `build` (float
-    tables in the params, one block).
+    Array fields: the int8 tables (one `PackedRows`, tables back to back:
+    (rows, d) int8 values and (rows / 128, 128) f32 scales) and the dense
+    weights; `cfg`, `candidates` and `top_k` are static. Build with
+    `from_row_blocks` (float rows streamed in fixed-size blocks, for
+    tables too large to hold in float), or `build` (float tables in the
+    params, one block).
     """
 
-    table_q: QuantizedTensor  # (rows, d) int8 + (rows, 1) f32 scales
+    table_q: PackedRows
     params: dict  # "bottom", "cross", "top" (models.recsys.init_dlrm_dense)
     cfg: rs.DLRMConfig = None
     candidates: int = 512
@@ -85,14 +105,14 @@ class DLRMEngine:
         n_rows = sum(cfg.rows)
         for lo, rows in blocks:
             if values is None:
-                size = -(-n_rows // rows.shape[0]) * rows.shape[0]
+                size = cdiv(n_rows, rows.shape[0]) * rows.shape[0]
                 values = jnp.zeros((size, cfg.embed_dim), jnp.int8)
-                scales = jnp.zeros((size, 1), jnp.float32)
+                scales = jnp.zeros((cdiv(size, SCALE_LANES), SCALE_LANES),
+                                   jnp.float32)
             values, scales = _ingest(values, scales, rows, lo)
             del rows
             scales.block_until_ready()
-        return DLRMEngine(table_q=QuantizedTensor(values=values,
-                                                  scales=scales),
+        return DLRMEngine(table_q=PackedRows(values=values, scales=scales),
                           params=dense, cfg=cfg, candidates=candidates,
                           top_k=top_k)
 
@@ -142,29 +162,49 @@ def slot_offsets(cfg: rs.DLRMConfig) -> np.ndarray:
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
 def _ingest(values, scales, rows, lo):
-    """Quantize float `rows` row-wise and write them in place at row
-    `lo` of the int8 tables."""
+    """Quantize float `rows` row-wise and write them in place at row `lo`
+    of the int8 tables, and their scales at flat position `lo`."""
     q = quantize_rowwise(rows)
+    flat = jax.lax.dynamic_update_slice(scales.reshape(-1),
+                                        q.scales.reshape(-1), (lo,))
     return (jax.lax.dynamic_update_slice(values, q.values, (lo, 0)),
-            jax.lax.dynamic_update_slice(scales, q.scales, (lo, 0)))
+            flat.reshape(scales.shape))
 
 
 def bag_sums(values: jax.Array, scales: jax.Array, ids: jax.Array,
              bags: tuple) -> jax.Array:
-    """Fixed-size multi-hot int8 bags by XLA's gather and sum: (n, d) int8
-    rows, (n, 1) f32 scales, (N, sum(bags)) row ids, bag after bag ->
-    (N, len(bags), d) f32. Bag j of sample i is the sum, added one slot
-    after the other, of its rows' int8 values times their row scales.
+    """Fixed-size multi-hot int8 bags by XLA's gather and sum, a slot at a
+    time: (n, d) int8 rows, (n / 128, 128) f32 scales (`PackedRows`),
+    (N, sum(bags)) row ids, bag after bag -> (N, len(bags), d) f32. Bag j
+    of sample i is the sum, added one slot after the other, of its rows'
+    int8 values times their row scales.
+
+    Id ``r``'s scale is lane ``r % 128`` of scale row ``r // 128``. Each
+    slot gathers its own N rows and scale rows, small enough for XLA to
+    keep in VMEM, and XLA fuses the scaling and sums onto them: no f32
+    copy of the gathered rows is written. On a v5e a gather costs about
+    the same a row whether the row holds 4 B or 512 B, and slot-sized
+    gathers cost less a row than one gather of every slot (PERF.md §6).
 
     Not `kernels.embedding_pool`, which DMAs a 128-row tile a bag slot. A
-    Pallas kernel of row-granular DMAs measured 2.3 times slower on a
-    v5e (PERF.md), so no kernel is registered for this op."""
-    rows = values[ids].astype(jnp.float32) * scales[ids]
+    Pallas kernel of row-granular DMAs measured 2.3 times slower than
+    XLA's gather of int8 rows on a v5e, and a Pallas pass pooling XLA's
+    gathered rows slower than this (PERF.md), so no kernel is registered
+    for this op."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, SCALE_LANES), 1)
+
+    def slot(j):
+        r = ids[:, j]
+        scale = jnp.sum(jnp.where(lane == (r % SCALE_LANES)[:, None],
+                                  scales[r // SCALE_LANES], 0.0),
+                        axis=-1, keepdims=True)
+        return values[r].astype(jnp.float32) * scale
+
     out, lo = [], 0
     for n in bags:
-        acc = rows[:, lo]
+        acc = slot(lo)
         for j in range(lo + 1, lo + n):
-            acc = acc + rows[:, j]
+            acc = acc + slot(j)
         out.append(acc)
         lo += n
     return jnp.stack(out, axis=1)

@@ -154,27 +154,102 @@ def test_observer_sees_the_rows_bags_read(mode):
     np.testing.assert_array_equal(np.sort(np.concatenate(seen)), want)
 
 
+def _unpacked(table):
+    """The tables (`PackedRows`) as (rows, d) int8 values and (rows, 1)
+    f32 scales, scale ``r`` from ``[r // 128, r % 128]``."""
+    return (np.asarray(table.values),
+            np.asarray(table.scales).reshape(-1, 1))
+
+
+def _table_rows(params, cfg=CFG):
+    return np.concatenate([np.asarray(params["tables"][f"cat_{i:02d}"])
+                           for i in range(cfg.n_sparse)])
+
+
+def _streamed(params, cfg=CFG, block=64):
+    """`from_row_blocks` over the tables in blocks of `block` rows."""
+    rows = _table_rows(params, cfg)
+    rows = np.concatenate([rows, np.zeros((-len(rows) % block,
+                                           rows.shape[1]), np.float32)])
+    blocks = ((lo, jnp.asarray(rows[lo:lo + block]))
+              for lo in range(0, len(rows), block))
+    dense = {k: v for k, v in params.items() if k != "tables"}
+    return DLRMEngine.from_row_blocks(dense, cfg, blocks)
+
+
 def test_row_blocks_ingest_like_build():
     """Streaming float row blocks into place gives the rows `build`
     quantizes from whole tables."""
     params = rs.init_dlrm(jax.random.key(0), CFG)
     built = DLRMEngine.build(params, CFG)
-    rows = np.concatenate([np.asarray(params["tables"][f"cat_{i:02d}"])
-                           for i in range(CFG.n_sparse)])
-    block = 64
-    pad = -len(rows) % block
-    rows = np.concatenate([rows, np.zeros((pad, rows.shape[1]), np.float32)])
-    blocks = ((lo, jnp.asarray(rows[lo:lo + block]))
-              for lo in range(0, len(rows), block))
-    dense = {k: v for k, v in params.items() if k != "tables"}
-    streamed = DLRMEngine.from_row_blocks(dense, CFG, blocks)
+    streamed = _streamed(params)
     n = sum(CFG.rows)
-    np.testing.assert_array_equal(
-        np.asarray(streamed.table_q.values)[:n],
-        np.asarray(built.table_q.values))
-    np.testing.assert_array_equal(
-        np.asarray(streamed.table_q.scales)[:n],
-        np.asarray(built.table_q.scales))
+    for got, want in zip(_unpacked(streamed.table_q),
+                         _unpacked(built.table_q)):
+        np.testing.assert_array_equal(got[:n], want[:n])
+
+
+@pytest.mark.parametrize("ingest", ["build", "from_row_blocks"])
+def test_packing_round_trips(ingest):
+    """The int8 rows and packed scales unpack to `quantize_rowwise`'s
+    rows and scales (jitted, as ingestion runs it), bit for bit; the
+    scales take no more room than the rows' own, but for padding to a
+    whole row of 128."""
+    params = rs.init_dlrm(jax.random.key(0), CFG)
+    engine = (DLRMEngine.build(params, CFG) if ingest == "build"
+              else _streamed(params))
+    want = jax.jit(quantize_rowwise)(jnp.asarray(_table_rows(params)))
+    n = sum(CFG.rows)
+    values, scales = _unpacked(engine.table_q)
+    np.testing.assert_array_equal(values[:n], np.asarray(want.values))
+    np.testing.assert_array_equal(scales[:n], np.asarray(want.scales))
+    assert 0 <= engine.table_q.scales.size - len(values) < 128
+
+
+def _oracle_bag_sums(values, scales, ids, bags):
+    """The lookup as it was written for (rows, d) int8 rows and (rows, 1)
+    scales: gather, scale, and add a bag's slots one after the other."""
+    rows = values[ids].astype(jnp.float32) * scales[ids]
+    out, lo = [], 0
+    for n in bags:
+        acc = rows[:, lo]
+        for j in range(lo + 1, lo + n):
+            acc = acc + rows[:, j]
+        out.append(acc)
+        lo += n
+    return jnp.stack(out, axis=1)
+
+
+@pytest.mark.parametrize("embed_dim", [16, 128])
+def test_bag_sums_match_int8_rows_bit_for_bit(embed_dim):
+    """`bag_sums` over the tables and packed scales gives the sums of the
+    int8 rows times their (rows, 1) scales, to the bit: ids of every
+    residue mod 128, id 0, the last row held, and one row repeated in a
+    bag.
+
+    Both sides are jitted: XLA's CPU backend may fuse a product into the
+    sum it feeds, rounding once, and does so alike for both at these
+    bag sizes (at the cell's 100-slot bag it fuses one and not the
+    other; the chip's comparison at the cell's shape is in PERF.md)."""
+    cfg = CFG._replace(embed_dim=embed_dim,
+                       bottom_dims=CFG.bottom_dims[:-1] + (embed_dim,))
+    params = rs.init_dlrm(jax.random.key(1), cfg)
+    engine = _streamed(params, cfg)
+    q = jax.jit(quantize_rowwise)(jnp.asarray(_table_rows(params, cfg)))
+    n, s = sum(cfg.rows), sum(cfg.bags)
+    rng = np.random.default_rng(4)
+    ids = rng.integers(0, n, (40, s)).astype(np.int32)
+    ids[0] = np.arange(s) + 100  # across a row of 128 scales
+    ids[1] = (np.arange(s) * 37) % 128 + 256  # scattered lanes of a row
+    ids[2] = 0  # padding requests read row 0
+    ids[3] = n - 1  # the last row held, in every slot
+    ids[4, 4:9] = 201  # one row five times in the bag of 5
+    bags = cfg.bags
+    got = jax.jit(de.bag_sums, static_argnums=3)(
+        engine.table_q.values, engine.table_q.scales, jnp.asarray(ids), bags)
+    want = jax.jit(_oracle_bag_sums, static_argnums=3)(
+        q.values, q.scales, jnp.asarray(ids), bags)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("direction", ["dlrm_to_recsys", "recsys_to_dlrm"])

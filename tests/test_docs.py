@@ -168,6 +168,7 @@ def _public_api():
         ("dlrm_lookup_step", dlrm_lookup_step),
         ("dlrm_rank_step", dlrm_rank_step),
         ("embedding_bag", dlrm_engine.bag_sums),
+        ("PackedRows", dlrm_engine.PackedRows),
         # NNS entries
         ("fixed_radius_nns", nns.fixed_radius_nns),
         ("BlockSummary", nns.BlockSummary),

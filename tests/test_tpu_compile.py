@@ -206,9 +206,9 @@ DLRM_ROWS = (10_000_000, 39060, 17295, 7424, 20265, 3, 7122, 1543, 63,
 @pytest.mark.parametrize("stage", ["lookup", "rank"])
 def test_dlrm_stages_compile(one_chip, stage):
     """DLRM-DCNv2's staged programs at published widths, for one chip's
-    tables (54,184,588 int8 rows of 128) and the cell's bucket of 16
+    tables (54,184,588 int8 rows of 128, their scales 128 to a row) and
+    the cell's bucket of 16
     requests of 512 candidates, at highest precision: they fit the chip."""
-    from repro.core.quantization import QuantizedTensor
     from repro.models import recsys as rs
     from repro.serving import dlrm_engine as de
 
@@ -222,9 +222,9 @@ def test_dlrm_stages_compile(one_chip, stage):
     as_spec = lambda x: _spec(one_chip, x.shape, x.dtype)  # noqa: E731
     rows = sum(DLRM_ROWS)
     engine = de.DLRMEngine(
-        table_q=QuantizedTensor(values=_spec(one_chip, (rows, 128), jnp.int8),
-                                scales=_spec(one_chip, (rows, 1),
-                                             jnp.float32)),
+        table_q=de.PackedRows(
+            values=_spec(one_chip, (rows, 128), jnp.int8),
+            scales=_spec(one_chip, (-(-rows // 128), 128), jnp.float32)),
         params=jax.tree.map(as_spec, dense), cfg=cfg)
     b, c = 16, 512
     batch = {"dense": _spec(one_chip, (b, c, 13), jnp.float32),
