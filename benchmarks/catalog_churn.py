@@ -23,8 +23,8 @@ and then exercises the epoch machinery:
   * block-summary soundness across churn: the incrementally-maintained
     `BlockSummary` must equal a cold `build_block_summary` over the final
     (sigs, mask) bitwise — before AND after compaction — and the
-    post-compaction pruned scan must serve the exact bits of a
-    prune-disabled engine (asserted, not sampled);
+    post-compaction pruned scan must serve the exact bits of the same
+    engine without a summary (asserted, not sampled);
   * an epoch swap under the `AsyncServer` ring at depth `--depth`:
     every query of the stream is asserted to equal exactly the epoch it
     was dispatched against — old epoch before the swap, new epoch after,
@@ -216,10 +216,10 @@ def rows(items: int, n_queries: int, batch: int, wave: int,
     _assert_summary_sound(cat.engine, "post-compaction")
     # the post-compact pruned scan serves the exact unpruned bits
     unpruned = sync_server(dataclasses.replace(
-        cat.engine, prune=False)).serve_many(probe)
+        cat.engine, block_summary=None)).serve_many(probe)
     _assert_stream_equal(np.stack([s.items for s in post]),
                          np.stack([s.items for s in unpruned]),
-                         "post-compaction pruned vs prune-disabled")
+                         "post-compaction pruned vs unpruned")
     out.append((
         f"serving/churn/compact_{items}", pause_s * 1e6,
         f"pause_ms={pause_s * 1e3:.1f};epoch={cat.epoch};"

@@ -419,10 +419,10 @@ def catalog_phase(grown, queries, sizes: Sizes, seed: int, *,
     t0 = time.perf_counter()
     batches = stacked(grown, queries, sizes.serve_batch)
     variants = {"pruned": grown,
-                "plain": dataclasses.replace(grown, prune=False)}
+                "plain": dataclasses.replace(grown, block_summary=None)}
     live = make_live(grown, sizes, seed + 4)
     variants["masked_pruned"] = live
-    variants["masked"] = dataclasses.replace(live, prune=False)
+    variants["masked"] = dataclasses.replace(live, block_summary=None)
     for name, eng in variants.items():
         nns = check_scan(f"catalog {name}", eng, batches[0], on_chip=on_chip)
     want_items, want_scores = reference_serve(live, batches)

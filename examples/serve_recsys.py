@@ -20,13 +20,13 @@ Every front-end is constructed through the one factory —
 
   PYTHONPATH=src python examples/serve_recsys.py [--queries 2000]
       [--mode sync|pipelined|concurrent] [--depth 2]
-      [--prune on|off|auto] [--scan-block N] [--report]
+      [--scan-block N] [--report]
 
-``--prune`` drives the engine's block-summary pruning knob (`auto` prunes
-whenever the scan streams; results are bit-identical either way) and
-``--scan-block`` forces the streaming plan — the demo catalog is small
-enough to route dense by default, where pruning never engages. The summary
-line reports the mean summary blocks touched per query on a sample batch.
+The engine prunes its scan by block summary whenever the scan streams
+(results are bit-identical either way); ``--scan-block`` forces the
+streaming plan — the demo catalog is small enough to route dense by
+default, where pruning never engages. The summary line reports the mean
+summary blocks touched per query on a sample batch.
 ``--report`` prints the per-stage latency breakdown of the timed run from
 the front-end's ticket span chains (docs/OBSERVABILITY.md).
 """
@@ -57,9 +57,6 @@ def main():
                     help="deprecated alias for --mode pipelined")
     ap.add_argument("--depth", type=int, default=2,
                     help="in-flight ring depth (pipelined/concurrent)")
-    ap.add_argument("--prune", choices=("on", "off", "auto"), default="auto",
-                    help="block-summary pruning: on/off/auto "
-                         "(auto prunes whenever the scan streams)")
     ap.add_argument("--scan-block", type=int, default=None,
                     help="streaming scan chunk (None routes by catalog "
                          "size; set e.g. 128 to stream the small demo "
@@ -76,11 +73,9 @@ def main():
     params, cfg = train(data, args.steps)
     freqs = np.bincount(data.histories[data.histories >= 0],
                         minlength=data.n_items)
-    prune = {"on": True, "off": False, "auto": None}[args.prune]
     engine = RecSysEngine.build(params, cfg, radius=112, n_candidates=50,
                                 top_k=10, hot_rows=args.hot_rows,
-                                item_freqs=freqs, prune=prune,
-                                scan_block=args.scan_block)
+                                item_freqs=freqs, scan_block=args.scan_block)
     knobs = ({} if args.mode == "sync" else {"depth": args.depth})
     batcher = make_server(engine, args.mode, max_batch=args.batch, **knobs)
     if args.mode != "sync":
@@ -129,7 +124,7 @@ def main():
         prune_note = (f"blocks touched {bt.mean():.1f}/{nb} per query "
                       f"(scan_frac {bt.mean() / nb:.3f})")
     else:
-        prune_note = "pruning inactive (dense plan or --prune off)"
+        prune_note = "pruning inactive (dense plan)"
     print(f"micro-batches: {stats['n_batches']}, "
           f"padding fraction {stats['padding_fraction']:.3f}, "
           f"hot-cache hit rate {stats['cache_hit_rate']:.3f}, "

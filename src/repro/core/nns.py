@@ -71,7 +71,7 @@ class NNSResult(NamedTuple):
     distances: jax.Array  # (q, max_candidates) int32, BIG where invalid
     counts: jax.Array  # (q,) int32 — total matches within radius
     # (q,) int32 — summary blocks whose lower bound admitted the query, or
-    # None when the scan ran unpruned (dense plan, no summary, prune=False)
+    # None when the scan ran unpruned (dense plan, or no summary)
     blocks_touched: jax.Array | None = None
     # () int32 — the streaming kernel's (query-tile, db-block) cells
     # scanned, cells whose candidate merge ran, and merge rounds run; None
@@ -306,7 +306,6 @@ def fixed_radius_nns(
     n_valid: jax.Array | int | None = None,  # rows >= n_valid never match
     superblock: int | None = None,  # streaming superblock rows (testing knob)
     summary: BlockSummary | None = None,  # block summary enabling pruning
-    prune: bool | None = None,  # None=auto (prune when summary given), False=off
 ) -> NNSResult:
     """All db items within Hamming `radius` of each query (bounded, sorted).
 
@@ -325,11 +324,10 @@ def fixed_radius_nns(
       superblock: streaming superblock size override (testing knob;
         results are superblock-invariant).
       summary: optional `BlockSummary` over `db_sigs` (built against an
-        eligibility superset of this scan's (db_mask, n_valid)); enables
-        block pruning on the streaming plan. Bit-identical results — the
-        bound is sound — plus a per-query `blocks_touched` counter.
-      prune: None (default) prunes whenever `summary` is given and the
-        plan streams; False disables pruning even with a summary.
+        eligibility superset of this scan's (db_mask, n_valid)); the
+        streaming plan prunes whenever one is given, and scans unpruned
+        without. Bit-identical results — the bound is sound — plus a
+        per-query `blocks_touched` counter.
     Returns:
       NNSResult of (q, K) indices (-1 padded), (q, K) distances (`BIG`
       where invalid), (q,) total within-radius counts, and (pruned scans
@@ -343,8 +341,7 @@ def fixed_radius_nns(
         # catalog cold shard). Same bits, different residency.
         return out_of_core_nns(
             query_sigs, db_sigs, radius, max_candidates, db_mask=db_mask,
-            scan_block=scan_block, n_valid=n_valid, summary=summary,
-            prune=prune)
+            scan_block=scan_block, n_valid=n_valid, summary=summary)
 
     n, words = db_sigs.shape
     use_stream = _plan_streams(n, scan_block)
@@ -352,7 +349,7 @@ def fixed_radius_nns(
 
     if use_stream:
         prune_blocks = blocks_touched = block_rows = None
-        if summary is not None and prune is not False:
+        if summary is not None:
             prune_blocks, blocks_touched = _prune_mask(
                 query_sigs, summary, radius)
             block_rows = summary.block_rows
@@ -408,7 +405,6 @@ def out_of_core_nns(
     scan_block: int | None = None,  # inner chunk-scan block (>0 or None)
     n_valid: int | None = None,
     summary: BlockSummary | None = None,
-    prune: bool | None = None,
     chunk_rows: int = OUTOFCORE_CHUNK_ROWS,
 ) -> NNSResult:
     """Fixed-radius NNS over a host-resident (memmapped) signature DB.
@@ -428,7 +424,7 @@ def out_of_core_nns(
     """
     block = DEFAULT_SCAN_BLOCK if not scan_block else scan_block
     prune_np = blocks_touched = block_rows = None
-    if summary is not None and prune is not False:
+    if summary is not None:
         pm, blocks_touched = _prune_mask_jit(
             jnp.asarray(query_sigs), summary, radius)
         prune_np = np.asarray(pm)
@@ -440,45 +436,6 @@ def out_of_core_nns(
         chunk_rows=chunk_rows)
     return NNSResult(indices=indices, distances=distances, counts=counts,
                      blocks_touched=blocks_touched)
-
-
-# pre-jitted entry for the scan: knobs that fix shapes/plans are static,
-# signatures and n_valid stay traced, so repeat calls at one batch shape
-# never retrace in the caller
-_fixed_radius_nns_jit = jax.jit(
-    fixed_radius_nns,
-    static_argnames=("radius", "max_candidates", "scan_block", "superblock",
-                     "prune"))
-
-
-def fixed_radius_nns_async(
-    query_sigs: jax.Array,  # (q, words) uint32
-    db_sigs: jax.Array,  # (n, words) uint32
-    radius: int,
-    max_candidates: int = 128,
-    db_mask: jax.Array | None = None,
-    *,
-    scan_block: int | None = None,
-    n_valid: jax.Array | int | None = None,
-    superblock: int | None = None,
-    summary: BlockSummary | None = None,
-    prune: bool | None = None,
-) -> NNSResult:
-    """Non-blocking filtering scan: dispatch and return device futures.
-
-    Same arguments and bit-identical results as `fixed_radius_nns`, but
-    the call never synchronizes with the host: it dispatches one pre-jitted
-    scan (dense or streaming per `scan_block`) and immediately returns an
-    `NNSResult` of in-flight device arrays. Callers overlap host work (or
-    further dispatches) with the scan and pay the sync only when they read
-    a result — e.g. `np.asarray(res.indices)` or `jax.block_until_ready`.
-    This is the entry the pipelined `serving.AsyncServer` pattern builds
-    on; use it directly when driving the scan outside an engine.
-    """
-    return _fixed_radius_nns_jit(
-        query_sigs, db_sigs, radius=radius, max_candidates=max_candidates,
-        db_mask=db_mask, scan_block=scan_block, n_valid=n_valid,
-        superblock=superblock, summary=summary, prune=prune)
 
 
 def _pad_queries_to_axis(mesh, query_axis, query_sigs):
@@ -517,7 +474,6 @@ def sharded_fixed_radius_nns(
     superblock: int | None = None,  # forwarded to the streaming scan
     db_mask: jax.Array | None = None,  # (n,) bool, row-sharded like db_sigs
     summary: BlockSummary | None = None,  # block summary over the padded DB
-    prune: bool | None = None,  # None=auto, False=off
 ):
     """Fixed-radius NNS with the item DB sharded across the mesh.
 
@@ -553,7 +509,7 @@ def sharded_fixed_radius_nns(
         query_sigs, q_pad = _pad_queries_to_axis(mesh, query_axis,
                                                  query_sigs)
     use_prune = (
-        summary is not None and prune is not False
+        summary is not None
         and _plan_streams(per_shard, scan_block)
         and per_shard % summary.block_rows == 0
         and summary.n_blocks * summary.block_rows == n)
@@ -569,8 +525,7 @@ def sharded_fixed_radius_nns(
         res = fixed_radius_nns(q_local, db_local, radius, local_k,
                                scan_block=scan_block, n_valid=local_valid,
                                superblock=superblock, db_mask=mask_local,
-                               summary=sum_local,
-                               prune=True if use_prune else False)
+                               summary=sum_local)
         gidx = jnp.where(
             res.indices >= 0, res.indices + shard * per_shard, -1
         )
@@ -635,7 +590,6 @@ def query_parallel_nns(
     superblock: int | None = None,
     db_mask: jax.Array | None = None,  # (n,) bool, replicated like db_sigs
     summary: BlockSummary | None = None,  # replicated with the catalog
-    prune: bool | None = None,  # None=auto, False=off
 ):
     """Fixed-radius NNS with the QUERY batch sharded over `mesh[query_axis]`.
 
@@ -650,7 +604,7 @@ def query_parallel_nns(
     padded, pad = _pad_queries_to_axis(mesh, query_axis, query_sigs)
     nv = jnp.asarray(
         db_sigs.shape[0] if n_valid is None else n_valid, jnp.int32)
-    use_prune = (summary is not None and prune is not False
+    use_prune = (summary is not None
                  and _plan_streams(db_sigs.shape[0], scan_block))
 
     def local_scan(q_local, db_local, nv_local, *rest):
@@ -661,8 +615,7 @@ def query_parallel_nns(
         res = fixed_radius_nns(q_local, db_local, radius, max_candidates,
                                scan_block=scan_block, n_valid=nv_local,
                                superblock=superblock, db_mask=mask_local,
-                               summary=sum_local,
-                               prune=True if use_prune else False)
+                               summary=sum_local)
         # the kernel's counters stay per device: this plan reports none
         return res._replace(scan_cells=None, merge_cells=None,
                             merge_rounds=None)
@@ -805,7 +758,6 @@ def delta_aware_nns(
     n_valid: jax.Array | int | None = None,
     superblock: int | None = None,
     summary: BlockSummary | None = None,  # block summary over the base
-    prune: bool | None = None,
 ) -> NNSResult:
     """Fixed-radius NNS over (read-only base) + (bounded delta shard).
 
@@ -820,7 +772,7 @@ def delta_aware_nns(
     base = fixed_radius_nns(query_sigs, db_sigs, radius, max_candidates,
                             db_mask=db_mask, scan_block=scan_block,
                             n_valid=n_valid, superblock=superblock,
-                            summary=summary, prune=prune)
+                            summary=summary)
     delta = delta_scan(query_sigs, delta_sigs, delta_ids, radius,
                        max_candidates)
     return merge_delta_candidates(base, delta, max_candidates)

@@ -68,7 +68,6 @@ from repro.serving.hot_cache import (
     HotRowCache,
     build_hot_cache,
     cached_embedding_bag,
-    cached_lookup,
     invalidate_rows,
     pin_rows,
     top_ids_by_freq,
@@ -128,7 +127,6 @@ __all__ = [
     "TieredCatalog",
     "build_hot_cache",
     "cached_embedding_bag",
-    "cached_lookup",
     "compact_engine",
     "default_buckets",
     "dlrm_lookup_step",

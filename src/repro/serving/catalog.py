@@ -13,6 +13,9 @@ MVCC-style mutable view that never blocks and never changes a served bit:
     rows + LSH signatures + global item ids, kept *sorted by id* so both
     the O(log D) membership probe (`searchsorted`, the hot-cache idiom) and
     the bounded candidate truncation stay exact;
+  * lookups and ranking read pending rows through
+    `hot_cache.cached_rows` with the delta as its overlay (delta > hot >
+    base > zero);
   * base rows that were deleted or overwritten are **tombstoned** via the
     engine's `item_mask`, threaded through every NNS plan (dense,
     streaming kernel, bank-sharded, query-parallel) like `n_valid`;
@@ -61,10 +64,8 @@ from repro.core.quantization import (
     quantize_rowwise,
 )
 from repro.serving.hot_cache import (
-    cached_rows,
     invalidate_rows,
     pin_rows,
-    pool_rows,
     top_ids_by_freq,
 )
 from repro.utils import pytree_dataclass
@@ -108,61 +109,6 @@ def empty_delta(capacity: int, embed_dim: int, words: int) -> DeltaShard:
 def delta_n_live(delta: DeltaShard) -> int:
     """Host-side count of occupied delta slots."""
     return int(np.sum(np.asarray(delta.ids) != EMPTY_ID))
-
-
-# ---------------------------------------------------------------------------
-# jit-side delta row resolution (feature pooling + candidate ranking)
-# ---------------------------------------------------------------------------
-def delta_rows(delta: DeltaShard, ids: jax.Array):
-    """ids (...,) -> (hit mask (...,), dequantized rows (..., d) f32).
-
-    Binary-search membership over the sorted live prefix (the hot-cache
-    `_probe` idiom). Rows dequantize with the exact formula of the cold
-    int8 path, so a delta hit bit-matches the rebuilt base row.
-    """
-    pos = jnp.searchsorted(delta.ids, ids)
-    pos = jnp.clip(pos, 0, delta.capacity - 1)
-    hit = (delta.ids[pos] == ids) & (ids >= 0)
-    rows = delta.values[pos].astype(jnp.float32) * delta.scales[pos]
-    return hit, rows
-
-
-def delta_cached_rows(delta: DeltaShard | None, cache, table, ids):
-    """Delta-aware drop-in for `hot_cache.cached_rows`.
-
-    Resolution order: delta shard (the only source holding a touched row's
-    current value — touched ids were invalidated from the hot cache the
-    moment they changed) > hot cache > cold int8 path. CacheStats semantics
-    are unchanged: lookups count valid ids, hits count hot-set probes — a
-    delta hit is not a cache hit, exactly as in a rebuilt engine whose
-    cache pins the same surviving hot set.
-
-    Ids beyond the current base table that miss the delta read ZERO rows
-    (not the clamped-gather last row): the engine and its compacted /
-    reference rebuilds have different base sizes, and an id that is
-    out-of-catalog on one side materializes as the canonical zero row on
-    the other — zeroing is the one resolution both sides agree on bit for
-    bit (e.g. a retired new-id still present in a user's history).
-    """
-    rows, stats = cached_rows(cache, table, ids)
-    if delta is None or delta.capacity == 0:
-        return rows, stats
-    in_range = (ids < table.values.shape[0])[..., None]
-    hit, drows = delta_rows(delta, ids)
-    return jnp.where(hit[..., None], drows,
-                     jnp.where(in_range, rows, 0.0)), stats
-
-
-def delta_cached_embedding_bag(delta, cache, table, ids, weights=None,
-                               mode: str = "sum"):
-    """Delta-aware drop-in for `hot_cache.cached_embedding_bag`.
-
-    The exact `pool_rows` reduction the frozen bag uses, over rows
-    resolved through the delta overlay — identical ops on identical
-    inputs, so pooling bit-matches a rebuilt engine's paths.
-    """
-    rows, stats = delta_cached_rows(delta, cache, table, ids)  # (B, L, d)
-    return pool_rows(rows, ids, weights, mode), stats
 
 
 # ---------------------------------------------------------------------------

@@ -219,40 +219,54 @@ def invalidate_rows(cache: HotRowCache | None, ids) -> HotRowCache | None:
                        capacity=cache.capacity)
 
 
-def _probe(cache: HotRowCache, ids: jax.Array):
-    """ids (...,) -> (hit mask (...,), position into hot_rows (...,))."""
-    pos = jnp.searchsorted(cache.hot_ids, ids)
-    pos = jnp.clip(pos, 0, cache.capacity - 1)
-    hit = (cache.hot_ids[pos] == ids) & (ids >= 0)
+def _probe(keys: jax.Array, ids: jax.Array):
+    """Membership of `ids` (...,) in the ascending id set `keys` (K,) ->
+    (hit mask (...,), position into `keys` (...,)). Negative ids never hit."""
+    pos = jnp.searchsorted(keys, ids)
+    pos = jnp.clip(pos, 0, keys.shape[0] - 1)
+    hit = (keys[pos] == ids) & (ids >= 0)
     return hit, pos
 
 
 def cached_rows(cache: HotRowCache | None, table: QuantizedTensor,
-                ids: jax.Array):
-    """Gather rows for `ids` (...,) -> ((..., d) f32, CacheStats).
+                ids: jax.Array, *, overlay=None):
+    """Resolve item rows for `ids` (...,) -> ((..., d) f32, CacheStats).
 
-    Hot ids come from the pinned f32 rows; cold ids take the int8
-    dequant-gather path. -1 ids yield zero rows (as `embedding.lookup`).
+    The one row-resolution order of every engine, whether its catalog is
+    frozen, live or tiered:
+
+      1. `overlay` (a `catalog.DeltaShard`: ascending int32 ids with
+         `EMPTY_ID` padding, int8 `values`, (m, 1) `scales`) — a live
+         engine's pending rows, or the tiered store's per-batch bytes;
+      2. within the table: the pinned f32 hot row if the id is hot, else
+         the int8 dequant-gather of the base row;
+      3. zero: -1 padding ids, and (with an overlay) ids past the table
+         that miss the overlay — an id out of catalog on one side of a
+         rebuild reads the canonical zero row on the other.
+
+    Stats count valid ids as lookups and hot-set probes as hits; an
+    overlay hit is not a cache hit.
     """
     valid = ids >= 0
     safe = jnp.maximum(ids, 0)
     cold = table.values[safe].astype(jnp.float32) * table.scales[safe]
     if cache is None or cache.capacity == 0:
         rows = jnp.where(valid[..., None], cold, 0.0)
-        return rows, CacheStats(
-            hits=jnp.int32(0),
-            lookups=jnp.sum(valid).astype(jnp.int32))
-    hit, pos = _probe(cache, ids)
-    rows = jnp.where(hit[..., None], cache.hot_rows[pos], cold)
-    rows = jnp.where(valid[..., None], rows, 0.0)
-    return rows, CacheStats(hits=jnp.sum(hit).astype(jnp.int32),
-                            lookups=jnp.sum(valid).astype(jnp.int32))
-
-
-def cached_lookup(cache: HotRowCache | None, table: QuantizedTensor,
-                  ids: jax.Array):
-    """Drop-in for `core.embedding.lookup` returning (rows, CacheStats)."""
-    return cached_rows(cache, table, ids)
+        stats = CacheStats(hits=jnp.int32(0),
+                           lookups=jnp.sum(valid).astype(jnp.int32))
+    else:
+        hit, pos = _probe(cache.hot_ids, ids)
+        rows = jnp.where(hit[..., None], cache.hot_rows[pos], cold)
+        rows = jnp.where(valid[..., None], rows, 0.0)
+        stats = CacheStats(hits=jnp.sum(hit).astype(jnp.int32),
+                           lookups=jnp.sum(valid).astype(jnp.int32))
+    if overlay is None or overlay.capacity == 0:
+        return rows, stats
+    in_range = (ids < table.values.shape[0])[..., None]
+    found, opos = _probe(overlay.ids, ids)
+    orows = overlay.values[opos].astype(jnp.float32) * overlay.scales[opos]
+    return jnp.where(found[..., None], orows,
+                     jnp.where(in_range, rows, 0.0)), stats
 
 
 def pool_rows(rows: jax.Array, ids: jax.Array,
@@ -260,10 +274,8 @@ def pool_rows(rows: jax.Array, ids: jax.Array,
               mode: str = "sum") -> jax.Array:
     """THE pooling reduction: (B, L, d) rows + (B, L) ids -> (B, d).
 
-    One definition shared by the cached bag below and the delta-aware bag
-    in `serving/catalog.py` — the frozen-vs-live bit-match contract
-    requires the two poolings to stay op-for-op identical, so they must be
-    the same ops.
+    One definition behind `cached_embedding_bag`, so frozen, live and
+    tiered engines pool with the same ops.
     """
     valid = (ids >= 0).astype(jnp.float32)
     w = valid if weights is None else weights.astype(jnp.float32) * valid
@@ -280,12 +292,15 @@ def cached_embedding_bag(
     ids: jax.Array,  # (B, L) int32, -1 padded
     weights: jax.Array | None = None,
     mode: str = "sum",
+    *,
+    overlay=None,
 ):
     """Drop-in for `core.embedding.embedding_bag` -> ((B, d), CacheStats).
 
     The pooling reduction is the same weighted contraction as the uncached
-    kernel reference, over rows sourced from the hot set or the int8 path —
-    identical inputs in identical order, so the result bit-matches.
+    kernel reference, over rows resolved by `cached_rows` (overlay, hot
+    set, int8 path) — identical inputs in identical order, so the result
+    bit-matches.
     """
-    rows, stats = cached_rows(cache, table, ids)  # (B, L, d)
+    rows, stats = cached_rows(cache, table, ids, overlay=overlay)  # (B, L, d)
     return pool_rows(rows, ids, weights, mode), stats

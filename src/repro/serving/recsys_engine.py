@@ -56,15 +56,12 @@ from repro.core.nns import (
 from repro.core.quantization import QuantizedTensor, quantize_rowwise
 from repro.core.topk import TopKResult, threshold_topk
 from repro.models import recsys as rs
-from repro.serving.catalog import (
-    delta_cached_embedding_bag,
-    delta_cached_rows,
-)
 from repro.serving.hot_cache import (
     CacheStats,
     HotRowCache,
     build_hot_cache,
     cached_embedding_bag,
+    cached_rows,
 )
 from repro.serving.server import Dispatched, Field
 from repro.utils import FrozenMapping, pytree_dataclass
@@ -80,7 +77,7 @@ class ServeResult(NamedTuple):
 
 @pytree_dataclass(meta_fields=(
     "cfg", "radius", "n_candidates", "top_k", "nns_mesh", "nns_axis",
-    "scan_block", "nns_query_axis", "prune"))
+    "scan_block", "nns_query_axis"))
 class RecSysEngine:
     """The deployed iMARS pipeline as a jit-able pytree.
 
@@ -120,7 +117,8 @@ class RecSysEngine:
     item_mask: jax.Array | None = None  # (n,) bool — alive base rows
     # per-block occupancy summary of item_sigs (core.nns.BlockSummary) —
     # a pytree leaf like delta/item_mask, kept fresh by serving/catalog.py
-    # on upsert/delete/compact; None disables pruning entirely
+    # on upsert/delete/compact; streaming scans prune when it is set, and
+    # None scans unpruned
     block_summary: object = None  # core.nns.BlockSummary | None
     cfg: rs.YoutubeDNNConfig = None
     radius: int = 96
@@ -130,17 +128,12 @@ class RecSysEngine:
     nns_axis: str | None = None
     scan_block: int | None = None  # filtering NNS: None=auto, 0=dense, >0=chunk
     nns_query_axis: str | None = None  # mesh axis scanning query blocks in parallel
-    # block pruning: None=auto (prune whenever a summary exists and the
-    # plan streams), False=force off, True=explicitly on (same as auto —
-    # the scan still needs a summary and a streaming plan to prune)
-    prune: bool | None = None
 
     @staticmethod
     def build(params: dict, cfg: rs.YoutubeDNNConfig, *, lsh_bits: int = 256,
               radius: int = 96, n_candidates: int = 50, top_k: int = 10,
               hot_rows: int = 0, item_freqs=None, uiet_freqs: dict | None = None,
-              scan_block: int | None = None, prune: bool | None = None,
-              key=None) -> "RecSysEngine":
+              scan_block: int | None = None, key=None) -> "RecSysEngine":
         """Quantize a trained YoutubeDNN into a serving engine.
 
         hot_rows: capacity of the per-table hot-row caches (0 disables).
@@ -149,9 +142,9 @@ class RecSysEngine:
         scan_block: filtering-stage NNS execution plan — None routes dense vs
         streaming automatically by catalog size, 0 forces the dense (q, n)
         path, a positive value forces the streaming scan with that chunk.
-        prune: block-summary pruning of the streaming scan — None=auto
-        (prune whenever the plan streams), False=off. Bit-identical either
-        way; pruned scans also report per-query `blocks_touched`.
+        The engine carries a block summary, so streaming scans prune
+        (bit-identical, plus per-query `blocks_touched`); an unpruned
+        reference is `dataclasses.replace(engine, block_summary=None)`.
         """
         key = jax.random.key(7) if key is None else key
         # cfg is static jit metadata -> its feature map must be hashable
@@ -176,7 +169,7 @@ class RecSysEngine:
             lsh_proj=proj, item_hot=item_hot, uiet_hot=uiet_hot,
             block_summary=build_block_summary(sigs),
             radius=radius, n_candidates=n_candidates, top_k=top_k,
-            scan_block=scan_block, prune=prune)
+            scan_block=scan_block)
 
     def shard(self, mesh: jax.sharding.Mesh, axis: str | None = None, *,
               query_axis: str | None = None) -> "RecSysEngine":
@@ -379,9 +372,9 @@ def _features(engine: RecSysEngine, batch: dict):
     # history pooling reads the ITEM table -> must see pending delta rows
     # (a re-embedded item in someone's history pools its new embedding,
     # exactly as a rebuilt engine would)
-    pooled, st = delta_cached_embedding_bag(
-        engine.delta, engine.item_hot, engine.item_table_q,
-        mask(batch["history"]), mode="mean")
+    pooled, st = cached_embedding_bag(
+        engine.item_hot, engine.item_table_q, mask(batch["history"]),
+        mode="mean", overlay=engine.delta)
     stats = stats + st
     feats.append(pooled)
     x = jnp.concatenate(feats, axis=-1)
@@ -398,12 +391,12 @@ def _nns(engine: RecSysEngine, q_sigs: jax.Array) -> NNSResult:
     into the exact rebuilt-table (distance, id) order
     (`core.nns.merge_delta_candidates`).
 
-    Every plan threads the engine's `block_summary` + `prune` knob down to
-    the scan: streaming plans skip summary blocks whose sound lower bound
-    exceeds the radius (bit-identical results, `blocks_touched` counters
-    in the NNSResult); dense plans and `prune=False` scan unpruned.
+    Every plan threads the engine's `block_summary` down to the scan:
+    streaming plans skip summary blocks whose sound lower bound exceeds
+    the radius (bit-identical results, `blocks_touched` counters in the
+    NNSResult); dense plans and engines without a summary scan unpruned.
     """
-    summary, prune = engine.block_summary, engine.prune
+    summary = engine.block_summary
     if engine.nns_mesh is not None and engine.nns_axis is not None:
         base = sharded_fixed_radius_nns(
             engine.nns_mesh, engine.nns_axis, q_sigs, engine.item_sigs,
@@ -411,7 +404,7 @@ def _nns(engine: RecSysEngine, q_sigs: jax.Array) -> NNSResult:
             n_valid=engine.item_table_q.shape[0],
             scan_block=engine.scan_block,
             query_axis=engine.nns_query_axis,
-            db_mask=engine.item_mask, summary=summary, prune=prune)
+            db_mask=engine.item_mask, summary=summary)
     elif engine.nns_mesh is not None:  # query-parallel only, db replicated
         # n_valid still matters: item_sigs may carry pad rows from an
         # earlier bank-sharded incarnation of this engine
@@ -419,13 +412,13 @@ def _nns(engine: RecSysEngine, q_sigs: jax.Array) -> NNSResult:
             engine.nns_mesh, engine.nns_query_axis, q_sigs, engine.item_sigs,
             engine.radius, engine.n_candidates, scan_block=engine.scan_block,
             n_valid=engine.item_table_q.shape[0],
-            db_mask=engine.item_mask, summary=summary, prune=prune)
+            db_mask=engine.item_mask, summary=summary)
     else:
         base = fixed_radius_nns(q_sigs, engine.item_sigs, engine.radius,
                                 engine.n_candidates,
                                 scan_block=engine.scan_block,
                                 db_mask=engine.item_mask,
-                                summary=summary, prune=prune)
+                                summary=summary)
     if engine.delta is None or engine.delta.capacity == 0:
         return base
     if engine.nns_mesh is not None and engine.nns_query_axis is not None:
@@ -484,8 +477,8 @@ def _rank(engine: RecSysEngine, batch: dict, cand: jax.Array,
     # is masked to -inf below either way. Candidate rows resolve through
     # the delta overlay (pending re-embeds/new items rank on their
     # current rows, not the stale base).
-    items, st = delta_cached_rows(engine.delta, engine.item_hot,
-                                  engine.item_table_q, cand)
+    items, st = cached_rows(engine.item_hot, engine.item_table_q, cand,
+                            overlay=engine.delta)
     genre = _per_device(engine, embedding_bag, engine.genre_table_q,
                         batch["genre"][:, None])
     B, N = cand.shape

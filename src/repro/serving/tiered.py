@@ -22,14 +22,16 @@ production embedding traffic is heavily skewed):
     holds pending upserts; touched ids are evicted from BOTH caches the
     moment they change, keeping `delta ∩ hot = ∅` and the pool honest.
 
-Row resolution order per served id: delta > pool > disk, with the hot
-cache consulted exactly as the all-RAM engine consults it. Serving is
-host-driven in three stages mirroring `recsys_engine`'s staged split —
-the host builds one per-batch *overlay* (the bytes every requested id
-resolves to), and jitted mirrors of `_features` / `_rank` consume it with
-op-for-op the same computation as the all-RAM path, so results AND
-`CacheStats` counters bit-match the all-RAM engine over the same state
-(tested against `to_ram_engine()` / `rebuild_reference()`).
+Byte resolution per served id: delta > pool > disk. The host gathers,
+per batch, the bytes every requested id resolves to into a sorted
+*overlay* — a `DeltaShard` value — and runs the engine's own stage
+programs (`recsys_engine.lookup_step` / `rank_stage_step`) with that
+overlay as the engine's delta, so `hot_cache.cached_rows` resolves each
+row exactly as it does for the all-RAM engine: results AND `CacheStats`
+counters bit-match the all-RAM engine over the same state (tested
+against `to_ram_engine()` / `rebuild_reference()`). Only the filtering
+scan stays host-driven (`out_of_core_nns` over the memmap, the delta
+scan, and their merge).
 
 Promotion/demotion (`rebalance`) recomputes the pool and hot tiers from
 the measured `item_freqs` counters — frequency descending, ties by
@@ -66,10 +68,8 @@ from repro.core.quantization import (
     dequantize_rowwise,
     quantize_rowwise,
 )
-from repro.core.topk import threshold_topk
-from repro.core.embedding import embedding_bag
 from repro.kernels.ops import madvise_dontneed, madvise_random
-from repro.models import recsys as rs
+from repro.serving import recsys_engine as rse
 from repro.serving.catalog import (
     DeltaFullError,
     DeltaShard,
@@ -80,13 +80,9 @@ from repro.serving.catalog import (
 from repro.serving.hot_cache import (
     CacheStats,
     HotRowCache,
-    _probe,
-    cached_embedding_bag,
     invalidate_rows,
-    pool_rows,
     top_ids_by_freq,
 )
-from repro.serving.recsys_engine import ServeResult
 
 _META = "meta.json"
 _FILES = {"values": ("values.int8.bin", np.int8),
@@ -236,95 +232,7 @@ def open_base_shard(directory: str):
     return shard, alive, summary
 
 
-# ---------------------------------------------------------------------------
-# jitted serve mirrors over the per-batch overlay
-# ---------------------------------------------------------------------------
-def _overlay_rows(cache: HotRowCache | None, ov_ids, ov_vals, ov_scales,
-                  ids):
-    """Tiered mirror of `catalog.delta_cached_rows` over an overlay.
-
-    The overlay (`ov_ids` sorted ascending int32 with `EMPTY_ID` padding,
-    `ov_vals`/`ov_scales` the int8 bytes each id resolves to) carries the
-    delta > pool > disk resolution the host performed for every id this
-    batch can request; ids absent from it (out-of-catalog) read zero rows.
-    The hot cache is probed exactly like the all-RAM path — hot rows are
-    pinned dequantized base bytes, disjoint from the delta — so rows AND
-    CacheStats come out bit-identical to `delta_cached_rows` on the
-    equivalent all-RAM engine.
-    """
-    valid = ids >= 0
-    pos = jnp.searchsorted(ov_ids, ids)
-    pos = jnp.clip(pos, 0, ov_ids.shape[0] - 1)
-    found = (ov_ids[pos] == ids) & valid
-    cold = ov_vals[pos].astype(jnp.float32) * ov_scales[pos]
-    lookups = jnp.sum(valid).astype(jnp.int32)
-    if cache is None or cache.capacity == 0:
-        rows = jnp.where(found[..., None], cold, 0.0)
-        return rows, CacheStats(hits=jnp.int32(0), lookups=lookups)
-    hit, hpos = _probe(cache, ids)
-    rows = jnp.where(hit[..., None], cache.hot_rows[hpos], cold)
-    rows = jnp.where(found[..., None], rows, 0.0)
-    return rows, CacheStats(hits=jnp.sum(hit).astype(jnp.int32),
-                            lookups=lookups)
-
-
-def _tiered_lookup(inner, batch, ov_ids, ov_vals, ov_scales):
-    """Mirror of `recsys_engine._features` (+ the query signing of
-    `_scan_stage`): UIET lookups stay all-RAM; history rows resolve
-    through the overlay. -> (u, pooled, q_sigs, stats)."""
-    valid = batch.get("valid")
-
-    def mask(ids):
-        if valid is None:
-            return ids
-        return jnp.where(valid[:, None], ids, -1)
-
-    stats = CacheStats.zero()
-    feats = []
-    for name in sorted(inner.cfg.user_features.keys()):
-        emb, st = cached_embedding_bag(
-            inner.uiet_hot.get(name), inner.tables_q[name],
-            mask(batch[name][:, None]))
-        feats.append(emb)
-        stats = stats + st
-    hist = mask(batch["history"])
-    rows, st = _overlay_rows(inner.item_hot, ov_ids, ov_vals, ov_scales,
-                             hist)
-    pooled = pool_rows(rows, hist, None, "mean")
-    stats = stats + st
-    feats.append(pooled)
-    x = jnp.concatenate(feats, axis=-1)
-    u = rs._mlp_apply(inner.params["filter_mlp"], x)
-    return u, pooled, lsh_signature(u, inner.lsh_proj), stats
-
-
-def _tiered_rank(inner, batch, cand, u, pooled, ov_ids, ov_vals, ov_scales):
-    """Mirror of `recsys_engine._rank` + the final-id selection of
-    `_rank_stage`, with candidate rows resolved through the overlay.
-    -> (final_items, topk, stats)."""
-    valid = batch.get("valid")
-    if valid is not None:
-        cand = jnp.where(valid[:, None], cand, -1)
-    items, st = _overlay_rows(inner.item_hot, ov_ids, ov_vals, ov_scales,
-                              cand)
-    genre = embedding_bag(inner.genre_table_q, batch["genre"][:, None])
-    B, N = cand.shape
-    ctx = jnp.concatenate([u, genre, pooled], axis=-1)
-    x = jnp.concatenate(
-        [jnp.broadcast_to(ctx[:, None], (B, N, ctx.shape[-1])), items],
-        axis=-1)
-    logits = rs._mlp_apply(inner.params["rank_mlp"], x)[..., 0]
-    ctr = jax.nn.sigmoid(logits)
-    ctr = jnp.where(cand >= 0, ctr, -jnp.inf)
-    top = threshold_topk(ctr, threshold=0.0, k=inner.top_k)
-    final = jnp.where(
-        top.indices >= 0,
-        jnp.take_along_axis(cand, jnp.maximum(top.indices, 0), 1), -1)
-    return final, top, st
-
-
-_tiered_lookup_jit = jax.jit(_tiered_lookup)
-_tiered_rank_jit = jax.jit(_tiered_rank)
+_sign_jit = jax.jit(lsh_signature)
 _delta_scan_jit = jax.jit(delta_scan, static_argnums=(3, 4))
 _merge_jit = jax.jit(merge_delta_candidates, static_argnums=(2,))
 
@@ -483,8 +391,8 @@ class TieredCatalog:
     def _resolve_bytes(self, ids: np.ndarray, *, use_delta: bool = True):
         """Host resolution of `ids` -> (present, vals, scales) through
         delta > pool > disk. Tombstoned base ids still resolve to their
-        (stale) base bytes — mirroring `delta_cached_rows`, which ignores
-        the alive mask on the cold path; retrieval correctness rests on
+        (stale) base bytes — as `hot_cache.cached_rows`, which ignores
+        the alive mask, does on the all-RAM engine; retrieval correctness rests on
         the NNS mask, not the row gather."""
         ids = np.asarray(ids, np.int64).reshape(-1)
         m = ids.size
@@ -520,17 +428,21 @@ class TieredCatalog:
         self.disk_rows += int(in_disk.sum())
         return (in_delta | in_pool | in_disk), vals, scales
 
-    def _build_overlay(self, ids):
-        """ids (any int shape) -> (ov_ids, ov_vals, ov_scales) on device:
-        the sorted byte overlay `_overlay_rows` probes. Fixed size
-        (= ids.size) per bucket shape, so the jitted mirrors compile once.
+    def _build_overlay(self, ids) -> DeltaShard:
+        """ids (any int shape) -> the sorted byte overlay those ids resolve
+        to, as a `DeltaShard` (ids absent from the catalog hold `EMPTY_ID`
+        and read zero rows). Its `sigs` is the inner engine's 1-row
+        placeholder: the lookup and rank never read it. Fixed size
+        (= ids.size) per bucket shape, so the stage programs compile once.
         """
         flat = np.asarray(ids, np.int64).reshape(-1)
         present, vals, scales = self._resolve_bytes(flat)
         ov_ids = np.where(present, flat, np.int64(EMPTY_ID)).astype(np.int32)
         order = np.argsort(ov_ids, kind="stable")
-        return (jnp.asarray(ov_ids[order]), jnp.asarray(vals[order]),
-                jnp.asarray(scales[order]))
+        return DeltaShard(ids=jnp.asarray(ov_ids[order]),
+                          values=jnp.asarray(vals[order]),
+                          scales=jnp.asarray(scales[order]),
+                          sigs=self.inner.item_sigs, capacity=flat.size)
 
     def rebalance(self) -> None:
         """Recompute pool + hot membership from `item_freqs`.
@@ -586,26 +498,31 @@ class TieredCatalog:
         self.n_observed += int(ids.size)
 
     # -- serving -------------------------------------------------------
-    def serve(self, batch: dict) -> ServeResult:
+    def serve(self, batch: dict) -> rse.ServeResult:
         """Serve one padded batch (the `RecSysEngine.serve` schema) from
-        the tiered store; bit-matches `to_ram_engine().serve(batch)`."""
+        the tiered store; bit-matches `to_ram_engine().serve(batch)`.
+
+        The lookup and rank are the engine's own stage programs over
+        `inner` with a per-batch overlay as its delta: the history ids'
+        bytes for the lookup, the candidates' for the rank."""
         hist_np = np.asarray(batch["history"])
         batch_j = {k: jnp.asarray(v) for k, v in batch.items()}
-        ov = self._build_overlay(hist_np)
-        u, pooled, q_sigs, stats = _tiered_lookup_jit(
-            self.inner, batch_j, *ov)
+        eng = dataclasses.replace(self.inner,
+                                  delta=self._build_overlay(hist_np))
+        u, pooled, stats = rse.lookup_step(eng, batch_j, CacheStats.zero())
+        q_sigs = _sign_jit(u, self.inner.lsh_proj)
         base = out_of_core_nns(
             q_sigs, self.base.sigs, self.inner.radius,
             self.inner.n_candidates, db_mask=self.alive,
-            scan_block=self.inner.scan_block, summary=self.summary,
-            prune=self.inner.prune)
+            scan_block=self.inner.scan_block, summary=self.summary)
         pending = _delta_scan_jit(q_sigs, self.delta.sigs, self.delta.ids,
                                   self.inner.radius, self.inner.n_candidates)
         nns = _merge_jit(base, pending, self.inner.n_candidates)
         cand_np = np.asarray(nns.indices)
-        ov2 = self._build_overlay(cand_np)
-        final, top, st = _tiered_rank_jit(
-            self.inner, batch_j, nns.indices, u, pooled, *ov2)
+        eng = dataclasses.replace(self.inner,
+                                  delta=self._build_overlay(cand_np))
+        final, top, stats = rse.rank_stage_step(
+            eng, batch_j, nns.indices, u, pooled, stats)
         final_np = np.asarray(final)
         self.observe(np.concatenate(
             [hist_np.reshape(-1).astype(np.int64),
@@ -616,8 +533,8 @@ class TieredCatalog:
         # working set), never O(every page ever touched)
         for m in (self.base.values, self.base.scales):
             madvise_dontneed(m)
-        return ServeResult(items=final, topk=top, nns=nns,
-                           cost=self.inner.query_cost(), stats=stats + st)
+        return rse.ServeResult(items=final, topk=top, nns=nns,
+                               cost=self.inner.query_cost(), stats=stats)
 
     # -- mutation ------------------------------------------------------
     def apply_updates(self, upsert_ids=None, upsert_rows=None,

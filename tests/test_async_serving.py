@@ -1,6 +1,6 @@
-"""Pipelined serving: the AsyncServer ring, the staged serve split, and the
-non-blocking scan entry are pure execution knobs — every configuration must
-bit-match the synchronous path (items, scores, AND cache counters)."""
+"""Pipelined serving: the AsyncServer ring and the staged serve split are
+pure execution knobs — every configuration must bit-match the synchronous
+path (items, scores, AND cache counters)."""
 import dataclasses
 
 import jax
@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.nns import fixed_radius_nns, fixed_radius_nns_async
 from repro.data import synthetic
 from repro.data.synthetic import serving_queries as _queries
 from repro.models import recsys as rs
@@ -168,31 +167,3 @@ def test_staged_steps_respect_engine_knobs(served):
                                       stats)
         np.testing.assert_array_equal(np.asarray(base.items),
                                       np.asarray(items))
-
-
-# ---------------------------------------------------------------------------
-# non-blocking scan entry
-# ---------------------------------------------------------------------------
-def test_fixed_radius_nns_async_bitmatches(key):
-    """The async entry is dispatch-only sugar: identical results to the
-    blocking call on both execution plans, plus n_valid masking."""
-    from repro.core.lsh import lsh_signature, make_lsh_projections
-
-    proj = make_lsh_projections(key, 16, 64)
-    x = jax.random.normal(jax.random.key(5), (37, 16))
-    sigs = lsh_signature(x, proj)
-    want = fixed_radius_nns(sigs[:4], sigs, radius=28, max_candidates=12)
-    mask = np.arange(37) % 2 == 0
-    for kw in ({}, {"scan_block": 16}, {"n_valid": 30},
-               {"db_mask": jnp.asarray(mask)},
-               {"scan_block": 16, "superblock": 16}):
-        got = fixed_radius_nns_async(sigs[:4], sigs, 28, 12, **kw)
-        ref = fixed_radius_nns(sigs[:4], sigs, 28, 12, **kw)
-        np.testing.assert_array_equal(np.asarray(ref.indices),
-                                      np.asarray(got.indices))
-        np.testing.assert_array_equal(np.asarray(ref.distances),
-                                      np.asarray(got.distances))
-        np.testing.assert_array_equal(np.asarray(ref.counts),
-                                      np.asarray(got.counts))
-    assert (np.asarray(want.indices) == np.asarray(
-        fixed_radius_nns_async(sigs[:4], sigs, 28, 12).indices)).all()

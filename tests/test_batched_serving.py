@@ -14,11 +14,10 @@ from repro.serving import (
     RecSysEngine,
     build_hot_cache,
     cached_embedding_bag,
-    cached_lookup,
     default_buckets,
     serve_step,
 )
-from repro.serving.hot_cache import CacheStats
+from repro.serving.hot_cache import CacheStats, cached_rows
 from repro.launch.mesh import make_mesh
 
 
@@ -34,7 +33,7 @@ def test_cached_lookup_bitmatches_uncached(table, rng):
     cache = build_hot_cache(table, freqs=rng.integers(1, 100, 200),
                             capacity=50)
     ids = jnp.asarray(rng.integers(-1, 200, size=(6, 9)), jnp.int32)
-    got, stats = cached_lookup(cache, table, ids)
+    got, stats = cached_rows(cache, table, ids)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(lookup(table, ids)))
     assert int(stats.lookups) == int((np.asarray(ids) >= 0).sum())
     assert 0 <= int(stats.hits) <= int(stats.lookups)
@@ -62,10 +61,10 @@ def test_hot_cache_pins_most_frequent_rows(table):
     cache = build_hot_cache(table, freqs=freqs, capacity=4)
     np.testing.assert_array_equal(np.asarray(cache.hot_ids), hot_set)
     # lookups of pinned rows are all hits
-    _, stats = cached_lookup(cache, table, jnp.asarray(hot_set))
+    _, stats = cached_rows(cache, table, jnp.asarray(hot_set))
     assert int(stats.hits) == 4 and int(stats.lookups) == 4
     # lookups of cold rows are all misses
-    _, stats = cached_lookup(cache, table, jnp.asarray([0, 1, 2]))
+    _, stats = cached_rows(cache, table, jnp.asarray([0, 1, 2]))
     assert int(stats.hits) == 0 and int(stats.lookups) == 3
 
 

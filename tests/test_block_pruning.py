@@ -164,14 +164,18 @@ def test_pruned_streaming_bit_matches(scan_block, superblock, masked):
 
 
 def test_prune_false_disables_and_drops_counter():
+    """Pruning is decided by the summary alone: the same streaming scan
+    with a summary prunes and counts blocks, without one it scans every
+    block, drops the counter, and returns the same bits."""
     rng = np.random.default_rng(8)
     queries, db = _clustered(rng)
     summary = build_block_summary(db, BR)
     res = fixed_radius_nns(jnp.asarray(queries), jnp.asarray(db), 12, K,
-                           scan_block=24, summary=summary, prune=False)
-    assert res.blocks_touched is None
+                           scan_block=24, summary=summary)
+    assert res.blocks_touched is not None
     plain = fixed_radius_nns(jnp.asarray(queries), jnp.asarray(db), 12, K,
-                             scan_block=24)
+                             scan_block=24, summary=None)
+    assert plain.blocks_touched is None
     _assert_same(res, plain)
 
 
@@ -306,7 +310,7 @@ except ImportError:  # pragma: no cover - hypothesis is in the CI image
 
 
 # ---------------------------------------------------------------------------
-# engine level: the prune knob routes without changing a bit
+# engine level: a summary prunes without changing a bit
 # ---------------------------------------------------------------------------
 def test_engine_prune_knob_serves_identically():
     from repro.data import synthetic
@@ -345,8 +349,8 @@ def test_engine_prune_knob_serves_identically():
     streaming = dataclasses.replace(eng, scan_block=24)
     queries = synthetic.serving_queries(data, range(12))
     base = None
-    for prune in (False, True, None):
-        e = dataclasses.replace(streaming, prune=prune)
+    for summary in (None, streaming.block_summary):
+        e = dataclasses.replace(streaming, block_summary=summary)
         out = MicroBatcher(e, max_batch=6).serve_many(queries)
         items = np.stack([o.items for o in out])
         scores = np.stack([o.scores for o in out])

@@ -34,7 +34,7 @@ from repro.serving import (
     invalidate_rows,
     pin_rows,
 )
-from repro.serving.hot_cache import INVALID_ID, cached_lookup
+from repro.serving.hot_cache import INVALID_ID, cached_rows
 from repro.launch.mesh import make_mesh
 
 
@@ -429,8 +429,8 @@ def test_invalidate_and_pin_rows_units(served):
     assert (np.asarray(out.hot_rows)[ids == INVALID_ID] == 0).all()
     # untouched ids still hit, with identical pinned rows
     keep = ids[ids != INVALID_ID][:4]
-    rows, st = cached_lookup(out, engine.item_table_q, jnp.asarray(keep))
-    ref, _ = cached_lookup(cache, engine.item_table_q, jnp.asarray(keep))
+    rows, st = cached_rows(out, engine.item_table_q, jnp.asarray(keep))
+    ref, _ = cached_rows(cache, engine.item_table_q, jnp.asarray(keep))
     np.testing.assert_array_equal(np.asarray(rows), np.asarray(ref))
     assert int(st.hits) == len(keep)
     # no-op invalidation returns the same cache object

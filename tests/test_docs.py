@@ -175,13 +175,12 @@ def _public_api():
         ("build_block_summary", nns.build_block_summary),
         ("update_block_summary", nns.update_block_summary),
         ("summary_block_bounds", nns.summary_block_bounds),
-        ("fixed_radius_nns_async", nns.fixed_radius_nns_async),
         ("sharded_fixed_radius_nns", nns.sharded_fixed_radius_nns),
         ("query_parallel_nns", nns.query_parallel_nns),
         ("cosine_topk", nns.cosine_topk),
         # hot cache
         ("build_hot_cache", hot_cache.build_hot_cache),
-        ("cached_lookup", hot_cache.cached_lookup),
+        ("cached_rows", hot_cache.cached_rows),
         ("cached_embedding_bag", hot_cache.cached_embedding_bag),
         # kernel registry
         ("register_kernel", ops.register_kernel),

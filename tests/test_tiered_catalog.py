@@ -11,9 +11,11 @@ on the all-RAM side), so the bit-match also cross-checks the two kernel
 drive paths against each other.
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.quantization import dequantize_rowwise, quantize_rowwise
 from repro.data import synthetic
 from repro.models import recsys as rs
 from repro.serving import (
@@ -25,7 +27,7 @@ from repro.serving import (
     open_base_shard,
     write_base_shard,
 )
-from repro.serving.hot_cache import INVALID_ID
+from repro.serving.hot_cache import INVALID_ID, cached_rows
 
 
 @pytest.fixture(scope="module")
@@ -330,3 +332,65 @@ def test_restore_guards(served, tmp_path):
     assert fresh.epoch == 0
     with pytest.raises(ValueError, match="does not match the opened"):
         fresh.restore(tmp_path / "snap")
+
+
+# ---------------------------------------------------------------------------
+# one row resolver for frozen, live and tiered catalogs
+# ---------------------------------------------------------------------------
+_KINDS = ("hot", "overlay", "base", "past", "pad")
+
+
+@pytest.fixture(scope="module")
+def resolved(served, tmp_path_factory):
+    """One catalog state held three ways — a live engine with one base row
+    re-embedded and pending, its frozen from-scratch rebuild, and a tiered
+    store over the same bytes — plus one id of each resolution kind and
+    the row each must read."""
+    engine, _, freqs = served
+    n, d = engine.item_table_q.values.shape
+    tiered = TieredCatalog.from_engine(
+        engine, str(tmp_path_factory.mktemp("resolve")), pool_rows=32,
+        item_freqs=freqs.copy())
+    # the tiered store ranks its own hot set from the measured counters:
+    # take a hot id pinned on both sides and base ids pinned on neither
+    hot = np.intersect1d(np.asarray(engine.item_hot.hot_ids),
+                         np.asarray(tiered.inner.item_hot.hot_ids))
+    hot = hot[hot != INVALID_ID]
+    cold = np.setdiff1d(np.arange(n), np.union1d(
+        np.asarray(engine.item_hot.hot_ids),
+        np.asarray(tiered.inner.item_hot.hot_ids)))
+    ids = dict(zip(_KINDS, (int(hot[0]), int(cold[0]), int(cold[1]),
+                            n + 5, -1)))
+    row = np.random.default_rng(3).normal(size=(1, d)).astype(np.float32)
+    live = LiveCatalog(engine, delta_capacity=8)
+    live.upsert(np.asarray([ids["overlay"]], np.int32), row)
+    tiered.upsert(np.asarray([ids["overlay"]], np.int32), row)
+    query = jnp.asarray([ids[k] for k in _KINDS], jnp.int32)
+    frozen = live.rebuild_reference()
+    sources = {
+        "frozen": (frozen.item_hot, frozen.item_table_q, frozen.delta),
+        "live": (live.engine.item_hot, live.engine.item_table_q,
+                 live.engine.delta),
+        "tiered": (tiered.inner.item_hot, tiered.inner.item_table_q,
+                   tiered._build_overlay(np.asarray(query))),
+    }
+    base = np.asarray(dequantize_rowwise(engine.item_table_q))
+    new = np.asarray(dequantize_rowwise(quantize_rowwise(jnp.asarray(row))))
+    want = np.stack([base[ids["hot"]], new[0], base[ids["base"]],
+                     np.zeros(d, np.float32), np.zeros(d, np.float32)])
+    return sources, query, want
+
+
+@pytest.mark.parametrize("catalog", ["frozen", "live", "tiered"])
+def test_cached_rows_resolves_every_kind_of_id(resolved, catalog):
+    """`hot_cache.cached_rows` is the one resolver: over the same bytes a
+    frozen, a live and a tiered catalog read a hot id from its pinned row,
+    a pending id from the overlay (the rebuilt table on the frozen side),
+    a base-only id from the int8 table, and zero for an id past the table
+    and for -1 padding; hot probes count as hits, valid ids as lookups."""
+    sources, query, want = resolved
+    cache, table, overlay = sources[catalog]
+    rows, stats = cached_rows(cache, table, query, overlay=overlay)
+    np.testing.assert_array_equal(np.asarray(rows), want)
+    assert int(stats.hits) == 1
+    assert int(stats.lookups) == 4
